@@ -14,10 +14,8 @@ bucket and regenerates ``src/repro/kernels/tuned_configs.json`` (the table
 """
 import argparse
 import json
-import subprocess
 import sys
 import traceback
-from pathlib import Path
 
 REGRESSION_THRESHOLD = 0.25   # fastest engine may not slow down >25%
 FUSED_ENGINE = "dense_pallas_fused"
@@ -277,11 +275,18 @@ SUITE_NAMES = ("counting", "mining", "corpus", "streaming", "serving",
 
 
 def _run_staticcheck() -> None:
-    """Shell to scripts/staticcheck.py --all: the bench harness is the one
-    entry point every CI smoke already exercises, so a broken checker (or a
-    dirty tree) fails fast here too."""
-    script = Path(__file__).resolve().parents[1] / "scripts" / "staticcheck.py"
-    subprocess.run([sys.executable, str(script), "--all"], check=True)
+    """The staticcheck ``--all`` run, in this process (a child Python would
+    compete for the chip this one holds): a broken checker or a dirty tree
+    fails the harness too."""
+    from repro.analysis import staticcheck
+
+    from .common import emit
+
+    report = staticcheck.run()
+    print(report["text"], file=sys.stderr)
+    if not report["ok"]:
+        raise RuntimeError(f"staticcheck: {len(report['findings'])} findings")
+    emit("staticcheck_files", 0.0, str(report["files_checked"]))
 
 
 def unknown_suites(chosen) -> list:
@@ -316,6 +321,9 @@ def main() -> None:
                     help="model-ranked candidates to wall-clock per bucket "
                          "in --autotune (default 3)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.setup()
     if args.autotune:
         raise SystemExit(run_autotune(top_k=args.autotune_topk))
     if args.compare:

@@ -8,12 +8,14 @@ import argparse
 import time
 
 
+from repro.launch import compile_cache
 from repro.core import MinerConfig, mine
 from repro.data.spikes import (NetworkConfig, embedded_episodes,
                                noise_pair_estimate, simulate)
 
 
 def main():
+    compile_cache.setup()
     ap = argparse.ArgumentParser()
     ap.add_argument("--duration", type=float, default=20.0,
                     help="simulated seconds (paper datasets: 20..4000)")

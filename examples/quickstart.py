@@ -11,6 +11,7 @@ runs it blocking:
 """
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.core import (EventStream, MinerConfig, MiningSessionServer,
                         StreamingMiner, cache_stats, count_fsm_numpy,
                         count_nonoverlapped, mine, plans_for_miner, serial,
@@ -18,6 +19,7 @@ from repro.core import (EventStream, MinerConfig, MiningSessionServer,
 
 
 def main():
+    compile_cache.setup()
     rng = np.random.default_rng(7)
 
     # An event stream: 6 event types, Poisson noise + an embedded cascade

@@ -10,12 +10,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch import compile_cache
 from repro.configs import get_config
 from repro.models import Model
 from repro.train import make_serve_step
 
 
 def main():
+    compile_cache.setup()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, default=64)
     ap.add_argument("--batch", type=int, default=4)

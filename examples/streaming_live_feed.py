@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.core import (EventStream, MinerConfig, StreamingMiner,
                         cache_stats, mine_arrays, warm)
 
@@ -38,6 +39,7 @@ def make_session(rng, n_types=6, duration=40.0, cascade_after=20.0):
 
 
 def main():
+    compile_cache.setup()
     rng = np.random.default_rng(11)
     n_types = 6
     types, times = make_session(rng, n_types)

@@ -13,11 +13,13 @@ dispatches regardless of host count.
 """
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.core import telemetry
 from repro.distributed.fault_tolerance import StragglerMonitor
 
 
 def main():
+    compile_cache.setup()
     rng = np.random.default_rng(3)
     hosts = [f"host{i}" for i in range(16)]
     mon = StragglerMonitor(window=30.0, repeat=3, min_count=2)

@@ -11,6 +11,7 @@ import time
 
 import jax
 
+from repro.launch import compile_cache
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticCorpus
 from repro.distributed.checkpoint import Checkpointer
@@ -23,6 +24,7 @@ from repro.train import make_train_step
 
 
 def main():
+    compile_cache.setup()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--seq-len", type=int, default=256)

@@ -1,8 +1,11 @@
 """Three-term roofline model from compiled dry-run artifacts.
 
-    T_compute    = FLOPs_total   / (chips * PEAK_FLOPS)
-    T_memory     = HBM_bytes     / (chips * HBM_BW)
-    T_collective = coll_bytes    / (chips * ICI_BW)
+    T_compute    = FLOPs_total   / (chips * peak FLOP/s)
+    T_memory     = HBM_bytes     / (chips * peak HBM bytes/s)
+    T_collective = coll_bytes    / (chips * peak ICI bytes/s)
+
+Peaks come from one table, :data:`PEAKS`, keyed by the device kind JAX
+reports; an unknown device raises instead of borrowing another's peaks.
 
 `cost_analysis()` on a GSPMD-partitioned module is **per-device** (verified
 by calibration in EXPERIMENTS.md §Roofline-notes: a 4.4 TFLOP global matmul
@@ -28,10 +31,35 @@ import json
 import re
 from typing import Dict, Optional
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12     # bf16
-HBM_BW = 819e9          # bytes/s
-ICI_BW = 50e9           # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one device kind."""
+    flops: float        # bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # chip-to-chip interconnect bytes/s per link
+    source: str
+
+
+#: Peaks keyed by ``jax.devices()[0].device_kind``. A device that is not
+#: here is an error wherever a time is divided by a peak, never a default.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s ICI per chip (4 links)"),
+}
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; unknown devices raise ``KeyError``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            f"analysis.roofline.PEAKS (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -102,14 +130,16 @@ class Roofline:
 def analyze(
     *, arch: str, shape: str, mesh_name: str, chips: int,
     cost: Dict[str, float], coll: Dict[str, float],
-    model_flops: float, memstats: Optional[dict] = None,
+    model_flops: float, device_kind: str,
+    memstats: Optional[dict] = None,
 ) -> Roofline:
+    peak = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     mem_bytes = float(cost.get("bytes accessed", 0.0))
     cb = float(coll.get("total", 0.0))
-    t_c = flops / PEAK_FLOPS            # per-device flops / per-chip peak
-    t_m = mem_bytes / HBM_BW
-    t_x = cb / ICI_BW
+    t_c = flops / peak.flops            # per-device flops / per-chip peak
+    t_m = mem_bytes / peak.hbm_bw
+    t_x = cb / peak.ici_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     t_max = max(t_c, t_m, t_x, 1e-30)

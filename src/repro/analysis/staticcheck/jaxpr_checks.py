@@ -21,10 +21,10 @@ dynamically:
   tracing.
 * **REPRO104** — Pallas tile contracts hold statically for the plan's
   resolved tiles and for every ``tuned_configs.json`` entry: the lcm-
-  padded capacity is covered exactly by the grid, tiles divide it, the
-  scalar-prefetched index map stays in bounds, and an analytic per-grid-
-  step VMEM estimate stays under the 16 MiB/core budget (the estimate is
-  also cross-checked against ``analysis.roofline`` byte accounting).
+  padded capacity is covered exactly by the tiles and the row layout, and
+  — for launches compiled for the chip — tiles are 128-lane multiples and
+  the kernel's own per-grid-step VMEM sizing
+  (``kernels.ops.kernel_vmem_bytes``) stays under its budget.
 """
 from __future__ import annotations
 
@@ -33,13 +33,14 @@ from contextlib import contextmanager
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jax_core
 
 from .findings import Finding
 
 __all__ = [
     "check_plan", "check_plans", "check_tuned_table", "check_hlo",
     "default_matrix", "full_matrix", "FORBIDDEN_PRIMITIVES",
-    "VMEM_BUDGET_BYTES", "estimate_vmem_bytes", "plan_path",
+    "estimate_vmem_bytes", "plan_path",
 ]
 
 #: Primitive names that imply a host round-trip inside a traced body.
@@ -53,7 +54,6 @@ FORBIDDEN_PRIMITIVES = frozenset({
 #: fns must never touch the seed row.
 EXPECTED_TMIN_APPLICATIONS = {"count_tail": 1}
 
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024   # ~16 MB/core (TPU v4/v5e class)
 
 
 def plan_path(plan) -> str:
@@ -74,9 +74,9 @@ def _subjaxprs(params: dict):
             item = stack.pop()
             if isinstance(item, (tuple, list)):
                 stack.extend(item)
-            elif isinstance(item, jax.core.ClosedJaxpr):
+            elif isinstance(item, jax_core.ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jax_core.Jaxpr):
                 yield item
 
 
@@ -195,74 +195,48 @@ def check_tmin(plan, n_restrictions: int) -> List[Finding]:
 # -- Pallas tile/grid/VMEM contracts ----------------------------------------
 
 
-def estimate_vmem_bytes(kind: str, levels: int, pcap: int, bn: int,
-                        bp: int, chunk: int) -> int:
-    """Analytic per-grid-step VMEM footprint of the two kernel families.
-
-    Conservative: operand/output blocks at 4 B/elem plus the dominant
-    in-kernel intermediates (the [.., BN, BP] window compare at 5 B/elem
-    for bool+f32 operands, gathers and compaction arrays at 4 B/elem).
-    The track estimate matches the documented BN + 2*BP + BN*BP shape in
-    kernels/episode_track.py; the count estimate covers a whole R-row
-    chunk across all levels (times block is [R, N, pcap]).
-    """
-    nt = max(1, pcap // bn)
-    if kind == "track":
-        # blocks: t_next bn, t_prev pcap, scratch (2, pcap), out bn + 1
-        blocks = 4 * (2 * bn + 3 * pcap + 1)
-        inter = 5 * bn * bp            # ok/where compare [BN, BP]
-        return blocks + inter
-    if kind == "count":
-        r = max(1, chunk)
-        n = levels + 1
-        blocks = 4 * r * (n * pcap + 2 * levels + 2 * levels * nt + 3)
-        compare = 5 * r * nt * bn * bp   # [R, NT, BN, BP] == [R, pcap, BP]
-        gathers = 2 * 4 * r * pcap       # tp/vp tile gathers
-        compact = 4 * 4 * r * pcap       # csum/src/sT/eT
-        return blocks + compare + gathers + compact
-    raise ValueError(f"unknown kernel kind {kind!r}")
+def estimate_vmem_bytes(kind: str, levels: int, cap: int, bn: int,
+                        bp: int, chunk: int, batch: int = 1) -> int:
+    """Per-grid-step chip VMEM of one kernel launch — the kernel's own
+    sizing rule (``kernels.ops.kernel_vmem_bytes``), not a second model."""
+    from ...kernels import ops
+    return ops.kernel_vmem_bytes(kind, levels, cap, bn, bp, chunk, batch)
 
 
 def _tile_contract(path: str, kind: str, levels: int, cap: int,
                    batch_rows: int, bn: int, bp: int, wt: int,
-                   chunk: int) -> List[Finding]:
+                   chunk: int, interpret: bool = False) -> List[Finding]:
+    """Tile/grid/VMEM contract of one launch. Compiled for the chip, tiles
+    must be 128-lane multiples and the launch must fit the VMEM budget;
+    interpret mode checks the tiling arithmetic only."""
     from ...kernels import ops
     out: List[Finding] = []
-    # kernels clamp tiles to the (padded) capacity before the divisibility
-    # check, exactly as track_*_pallas do
-    ebn, ebp, pcap = ops.tile_geometry(cap, bn, bp)
-    ebn = min(ebn, pcap)
-    ebp = min(ebp, pcap)
+    try:
+        ebn, ebp, pcap = ops.tile_geometry(cap, bn, bp, interpret=interpret)
+    except ValueError as err:
+        return [Finding(path, 0, "REPRO104", str(err))]
     if pcap < cap:
         out.append(Finding(path, 0, "REPRO104",
                            f"padded cap {pcap} < cap {cap}"))
-    if pcap % ebn or pcap % ebp:
+    if pcap % ebn or pcap % ebp or pcap % ops.lanes(ebn, ebp):
         out.append(Finding(
             path, 0, "REPRO104",
             f"tiles ({ebn},{ebp}) do not divide padded cap {pcap} — "
             "pallas_call would raise at launch"))
         return out
-    next_tiles = pcap // ebn
-    prev_tiles = pcap // ebp
-    if next_tiles * ebn != pcap:
+    if wt < 0:
         out.append(Finding(path, 0, "REPRO104",
-                           f"grid {next_tiles}x{ebn} != padded cap {pcap} "
-                           "(inexact coverage)"))
-    # index-map bound: start_tile is clipped to [0, prev_tiles - wt_eff],
-    # so st[i] + j <= prev_tiles - 1 must hold for all j < wt_eff
-    wt_eff = prev_tiles if wt == 0 else min(wt, prev_tiles)
-    max_start = max(prev_tiles - wt_eff, 0)
-    if max_start + wt_eff > prev_tiles:
-        out.append(Finding(path, 0, "REPRO104",
-                           f"index map out of bounds: start {max_start} + "
-                           f"window {wt_eff} > prev tiles {prev_tiles}"))
-    vmem = estimate_vmem_bytes(kind, max(1, levels), pcap, ebn, ebp, chunk)
-    if vmem > VMEM_BUDGET_BYTES:
+                           f"negative window_tiles {wt}"))
+    if interpret:
+        return out
+    vmem = estimate_vmem_bytes(kind, max(1, levels), cap, ebn, ebp, chunk,
+                               batch_rows)
+    if vmem > ops.VMEM_BUDGET_BYTES:
         out.append(Finding(
             path, 0, "REPRO104",
             f"estimated VMEM {vmem / 2**20:.2f} MiB per grid step exceeds "
-            f"the {VMEM_BUDGET_BYTES // 2**20} MiB budget "
-            f"(kind={kind}, pcap={pcap}, bn={ebn}, bp={ebp}, "
+            f"the {ops.VMEM_BUDGET_BYTES // 2**20} MiB budget "
+            f"(kind={kind}, cap={cap}, bn={ebn}, bp={ebp}, "
             f"chunk={chunk})"))
     return out
 
@@ -270,19 +244,23 @@ def _tile_contract(path: str, kind: str, levels: int, cap: int,
 def check_plan_tiles(plan) -> List[Finding]:
     if plan.tile_cap < 1:
         return []  # malformed plans are uncacheable_reason'd, not tiled
+    from ...kernels import ops
     return _tile_contract(
         plan_path(plan), plan.kind, plan.level - 1, plan.tile_cap,
         max(plan.streams, 1) * plan.batch, plan.block_next,
-        plan.block_prev, plan.window_tiles, plan.chunk)
+        plan.block_prev, plan.window_tiles, plan.chunk,
+        interpret=ops.resolve_interpret(plan.interpret))
 
 
 _KEY_RE = re.compile(r"^(count|track):L(\d+):N(\d+):B(\d+)$")
 
 
 def check_tuned_table(path: Optional[str] = None) -> List[Finding]:
-    """Static contract check of every tuned_configs.json entry."""
+    """Static contract check of every tuned_configs.json entry, on the
+    backend the table serves (chip rules for a ``tpu`` table)."""
     from ...kernels import autotune
     table = autotune.load_table(path)
+    backend = autotune.table_backend(path) or "tpu"
     out: List[Finding] = []
     src = "src/repro/kernels/tuned_configs.json"
     for key, cfg in sorted(table.items()):
@@ -293,11 +271,13 @@ def check_tuned_table(path: Optional[str] = None) -> List[Finding]:
             continue
         kind, levels, cap, batch = (m.group(1), int(m.group(2)),
                                     int(m.group(3)), int(m.group(4)))
-        resolved = autotune.resolve(kind, levels, cap, batch)
+        resolved = autotune.resolve(kind, levels, cap, batch, path=path,
+                                    backend=backend)
         out.extend(_tile_contract(
             f"{src}#{key}", kind, levels, cap, batch,
             resolved.block_next, resolved.block_prev,
-            resolved.window_tiles, resolved.chunk))
+            resolved.window_tiles, resolved.chunk,
+            interpret=backend != "tpu"))
     return out
 
 
@@ -323,11 +303,7 @@ def check_hlo(plan) -> Tuple[List[Finding], Dict[str, float]]:
                                       or "host" in line.lower()):
             out.append(Finding(path, i, "REPRO101",
                                "host/callback custom-call in compiled HLO"))
-    try:
-        costs = hlo_costs.module_costs(text)
-    except Exception:  # parser is best-effort across jax/XLA versions
-        costs = {}
-    return out, costs
+    return out, hlo_costs.module_costs(text)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,8 @@ __all__ = ["discover_files", "changed_files", "run", "REPO_MARKERS",
 TEXT_SUFFIXES = (".py", ".yml", ".yaml", ".toml", ".json")
 
 _SKIP_DIRS = {".git", "__pycache__", ".claude", ".pytest_cache",
-              "node_modules", ".venv"}
+              "node_modules", ".venv", ".jax_cache", "chiprun_out",
+              "_archive"}
 
 REPO_MARKERS = ("pyproject.toml", ".git")
 

@@ -1,72 +1,25 @@
-"""Version-compatibility shims for jax APIs that moved between releases.
-
-Keeps the rest of the codebase on one spelling regardless of the installed
-jax: ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-``jax.shard_map``, and mesh ``axis_types`` only exist on newer versions
-(see launch/mesh.py for the latter).
-"""
+"""One spelling for the shard_map calls the miner makes (JAX 0.9)."""
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # older jax (< 0.6)
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
+shard_map = jax.shard_map
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication checker disabled.
+    """``shard_map`` with the varying-manual-axes checker off.
 
     Needed when the body contains a ``pallas_call``: the checker has no
-    replication rule for it ("No replication rule for pallas_call"). The
-    disabling kwarg moved across jax releases (``check_rep`` ->
-    ``check_vma``), and the newest versions may drop it entirely once the
-    rule exists — pick by signature so a genuine TypeError from shard_map
-    itself (bad specs, bad mesh) propagates instead of being swallowed.
+    rule for it.
     """
-    import inspect
-
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):      # C-accelerated / wrapped callable
-        params = None
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if params is not None:
-        for name in ("check_rep", "check_vma"):
-            if name in params:
-                kwargs[name] = False
-                break
-        return shard_map(f, **kwargs)
-    # signature unavailable: probe, but re-raise the bare call's real error
-    for name in ("check_rep", "check_vma"):
-        try:
-            return shard_map(f, **kwargs, **{name: False})
-        except TypeError:
-            continue
-    return shard_map(f, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pcast_varying(x, axes):
-    """Mark ``x`` as varying over manual ``axes`` inside shard_map.
-
-    Newer jax enforces varying-manual-axes typing on scan carries and
-    provides ``lax.pcast`` to coerce; older versions have neither the check
-    nor the primitive, so this is an identity there.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axes, to="varying")
+    """Mark ``x`` as varying over manual ``axes`` inside shard_map (scan
+    carries must be typed varying)."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
-def axis_size(axis_name):
-    """Size of a mapped mesh axis; ``lax.axis_size`` on newer jax, the
-    psum-of-ones identity on older versions."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-__all__ = ["shard_map", "shard_map_unchecked", "pcast_varying", "axis_size"]
+__all__ = ["shard_map", "shard_map_unchecked", "pcast_varying"]

@@ -11,7 +11,7 @@ engine registry in tracking.py:
   engine="dense_pallas_fused"     dense tracking for an entire candidate
                                   batch in ONE fused Pallas launch: levels
                                   carried in VMEM scratch, scan offsets
-                                  scalar-prefetched, dynamic window walk;
+                                  read from per-row SMEM tables, dynamic walk;
                                   batched dispatch via ``track_batch``
   engine="count_scan_write"       paper's preferred lock-free pipeline:
                                   backward tracking + count/scan/write

@@ -218,18 +218,13 @@ def _count_sharded_batch_impl(
     # the sharded path needs raw intervals for ownership masking + the
     # cross-shard merge, so it always tracks (kind="track" tuned tiles) —
     # the single-launch count pipeline cannot serve it
-    try:
-        from ..kernels import autotune  # deferred: core importable sans pallas
-        tc = autotune.resolve(
-            "track", symbols.shape[1] - 1, cap_view, symbols.shape[0],
-            block_next=block_next, block_prev=block_prev,
-            window_tiles=window_tiles)
-        block_next, block_prev, window_tiles = (
-            tc.block_next, tc.block_prev, tc.window_tiles)
-    except ImportError:
-        block_next = 256 if block_next is None else block_next
-        block_prev = 256 if block_prev is None else block_prev
-        window_tiles = 0 if window_tiles is None else window_tiles
+    from ..kernels import autotune  # deferred: avoid import cycles
+    tc = autotune.resolve(
+        "track", symbols.shape[1] - 1, cap_view, symbols.shape[0],
+        block_next=block_next, block_prev=block_prev,
+        window_tiles=window_tiles)
+    block_next, block_prev, window_tiles = (
+        tc.block_next, tc.block_prev, tc.window_tiles)
     cfg = tracking.EngineConfig(
         cap_occ=cap_occ, max_window=max_window, block_next=block_next,
         block_prev=block_prev, window_tiles=window_tiles, interpret=interpret)

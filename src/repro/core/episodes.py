@@ -9,8 +9,73 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# ---------------------------------------------------------------------------
+# Exact window arithmetic on f32 times
+# ---------------------------------------------------------------------------
+# A window test ``lo < t - s <= hi`` must not depend on how ``t - hi``
+# rounds: at stream times of ~100 s one f32 ulp is ~8e-6, so a rounded
+# threshold admits or drops pairs that sit within an ulp of the window edge,
+# and counts drift from the exact FSM oracle. Every engine tests windows
+# through the two thresholds below, which are the f32 values on the right
+# side of the exact real result (an error-free TwoSum, then one ulp step).
+
+
+def _round_error(a, b, total):
+    """``a + b - total`` exactly, for ``total = fl(a + b)`` (TwoSum)."""
+    b_part = total - a
+    return (a - (total - b_part)) + (b - b_part)
+
+
+def _ulp_step(x, up: bool):
+    """The f32 next to finite, nonzero ``x`` toward +inf (``up``) or -inf."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    step = jnp.where(x > 0, 1, -1) if up else jnp.where(x > 0, -1, 1)
+    return jax.lax.bitcast_convert_type(bits + step, jnp.float32)
+
+
+def sub_ceil(t, c):
+    """Least f32 ``>=`` the exact ``t - c``: ``s >= sub_ceil(t, c)`` iff
+    ``t - s <= c`` and ``s < sub_ceil(t, c)`` iff ``t - s > c``, exactly,
+    for f32 ``s``. Infinite ``t`` passes through."""
+    t = jnp.asarray(t, jnp.float32)
+    c = jnp.asarray(c, jnp.float32)
+    x = t - c
+    err = _round_error(t, -c, x)
+    return jnp.where((err > 0) & (x != 0), _ulp_step(x, True), x)
+
+
+def add_floor(s, c):
+    """Greatest f32 ``<=`` the exact ``s + c``: ``t <= add_floor(s, c)`` iff
+    ``t - s <= c`` and ``t > add_floor(s, c)`` iff ``t - s > c``, exactly,
+    for f32 ``t``."""
+    s = jnp.asarray(s, jnp.float32)
+    c = jnp.asarray(c, jnp.float32)
+    x = s + c
+    err = _round_error(s, c, x)
+    return jnp.where((err < 0) & (x != 0), _ulp_step(x, False), x)
+
+
+def in_window(t, s, lo, hi):
+    """``lo < t - s <= hi`` exactly (f32 operands, broadcasting)."""
+    return (s >= sub_ceil(t, hi)) & (s < sub_ceil(t, lo))
+
+
+def window_f32(x) -> np.ndarray:
+    """Float32 window bounds for float64 ones: the largest f32 ``<= x``.
+
+    For every f32 gap ``d``, ``d <= window_f32(hi)`` iff ``d <= hi`` and
+    ``d > window_f32(lo)`` iff ``d > lo`` — the engines' f32 windows decide
+    exactly as the float64 bounds an episode was given (round to nearest
+    would move a bound like ``1 - 2**-53`` up onto the gap ``1.0``)."""
+    x64 = np.asarray(x, np.float64)
+    x32 = x64.astype(np.float32)
+    down = np.nextafter(x32, np.float32(-np.inf), dtype=np.float32)
+    return np.where(x32.astype(np.float64) > x64, down, x32).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +118,8 @@ class Episode:
     def as_arrays(self):
         return (
             jnp.asarray(self.symbols, jnp.int32),
-            jnp.asarray(self.t_low, jnp.float32),
-            jnp.asarray(self.t_high, jnp.float32),
+            jnp.asarray(window_f32(self.t_low)),
+            jnp.asarray(window_f32(self.t_high)),
         )
 
     def __str__(self):
@@ -95,6 +160,6 @@ def episode_batch(episodes: Sequence[Episode]):
     if len(ns) != 1:
         raise ValueError("episode_batch requires equal-length episodes")
     sym = np.asarray([e.symbols for e in episodes], np.int32)
-    lo = np.asarray([e.t_low for e in episodes], np.float32).reshape(len(episodes), -1)
-    hi = np.asarray([e.t_high for e in episodes], np.float32).reshape(len(episodes), -1)
+    lo = window_f32([e.t_low for e in episodes]).reshape(len(episodes), -1)
+    hi = window_f32([e.t_high for e in episodes]).reshape(len(episodes), -1)
     return jnp.asarray(sym), jnp.asarray(lo), jnp.asarray(hi)

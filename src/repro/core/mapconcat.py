@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .episodes import Episode
+from .episodes import Episode, in_window
 from .events import EventStream
 from .statemachine import NEG
 from .tracking import Occurrences
@@ -95,8 +95,8 @@ def count_mapconcat(
             valid = jnp.isfinite(t)
 
             def match_prev(j):
-                ok = (bufs[j - 1] > NEG) & (t - bufs[j - 1] > lo[j - 1]) & (
-                    t - bufs[j - 1] <= hi[j - 1])
+                ok = (bufs[j - 1] > NEG) & in_window(
+                    t, bufs[j - 1], lo[j - 1], hi[j - 1])
                 any_ok = jnp.any(ok)
                 # latest start among matching predecessors (dominance)
                 st = jnp.max(jnp.where(ok, bstarts[j - 1], NEG))

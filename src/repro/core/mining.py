@@ -40,7 +40,8 @@ from jax.sharding import Mesh
 from . import counting, distributed
 from . import events as events_lib
 from . import plan as plan_mod
-from .episodes import Episode, episode_batch, episodes_from_rows
+from .episodes import (Episode, episode_batch, episodes_from_rows,
+                       window_f32)
 from .events import EventStream
 
 MAX_BATCH_PAD = 16  # minimum candidate-batch capacity class (see _pad_to)
@@ -226,8 +227,8 @@ def pad_candidate_rows(cands: np.ndarray, level: int, cfg: MinerConfig):
     b = cands.shape[0]
     bp = _pad_to(b)
     sym = np.concatenate([cands, np.broadcast_to(cands[:1], (bp - b, level))])
-    lo = jnp.full((bp, level - 1), cfg.t_low, jnp.float32)
-    hi = jnp.full((bp, level - 1), cfg.t_high, jnp.float32)
+    lo = jnp.full((bp, level - 1), window_f32(cfg.t_low))
+    hi = jnp.full((bp, level - 1), window_f32(cfg.t_high))
     return jnp.asarray(sym), lo, hi
 
 

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 __all__ = [
     "MiningPlan", "plan_for", "dispatch", "warm", "register_fn",
     "cache_stats", "reset_cache", "set_cache_size", "cached_plans",
+    "cached_executable",
     "cache_disabled", "trace_counts", "plan_trace_counts",
     "reset_trace_counts", "pow2_ceil", "capacity_class", "pad_rows",
     "pad_width", "plans_for_miner",
@@ -115,10 +116,10 @@ class MiningPlan:
     parallel_schedule: bool = False
     cap_occ: Optional[int] = None
     max_window: int = 32
-    block_next: int = 256    # resolved tiles (autotune bucket of this plan)
-    block_prev: int = 256
+    block_next: int = 128    # resolved tiles (autotune bucket of this plan)
+    block_prev: int = 128
     window_tiles: int = 0
-    chunk: int = 8
+    chunk: int = 32
     interpret: Optional[bool] = None
     kind: str = "track"      # autotune kernel kind ("count" | "track")
     tile_cap: int = 0        # cap the tile bucket was resolved at (== cap,
@@ -129,11 +130,7 @@ class MiningPlan:
         """The tuned-tile bucket this plan resolves through — plan bucket
         and tile bucket are the same key (regression-tested against every
         entry in ``kernels/tuned_configs.json``)."""
-        try:
-            from ..kernels import autotune
-        except ImportError:
-            return (f"{self.kind}:L{self.level - 1}:N{pow2_ceil(self.tile_cap)}"
-                    f":B{pow2_ceil(max(self.streams, 1) * self.batch)}")
+        from ..kernels import autotune  # deferred: avoid import cycles
         return autotune.bucket_key(
             self.kind, self.level - 1, self.tile_cap,
             max(self.streams, 1) * self.batch)
@@ -162,13 +159,7 @@ def resolve_tiles(
     if kind is None:
         kind = ("count" if getattr(eng, "count_batch", None) is not None
                 else "track")
-    try:
-        from ..kernels import autotune  # deferred: core importable sans pallas
-    except ImportError:
-        return (256 if block_next is None else int(block_next),
-                256 if block_prev is None else int(block_prev),
-                0 if window_tiles is None else int(window_tiles),
-                8 if chunk is None else int(chunk), kind)
+    from ..kernels import autotune  # deferred: avoid import cycles
     cfg = autotune.resolve(
         kind, levels, cap, batch, block_next=block_next,
         block_prev=block_prev, window_tiles=window_tiles, chunk=chunk)
@@ -403,6 +394,13 @@ def cached_plans() -> List[MiningPlan]:
     return _CACHE.plans()
 
 
+def cached_executable(plan: MiningPlan):
+    """The compiled executable cached for ``plan`` (KeyError if none) —
+    e.g. to inspect its HLO (``.as_text()``) without recompiling."""
+    with _CACHE._lock:
+        return _CACHE._data[plan]
+
+
 def set_cache_size(maxsize: int) -> None:
     """Shrink/grow the LRU bound; shrinking evicts oldest entries now."""
     with _CACHE._lock:
@@ -478,14 +476,9 @@ def dispatch(plan: MiningPlan, *args):
     if exe is None:
         exe = _compile(plan, entry)
         _CACHE.insert(plan, exe)
-    try:
-        return exe(*args)
-    except (TypeError, ValueError) as err:  # aval mismatch: adapter misuse
-        warnings.warn(
-            f"cached executable rejected inputs ({err}); "
-            "dispatching uncached", stacklevel=2)
-        _CACHE.fallbacks += 1
-        return jax.jit(entry.build(plan))(*args)
+    # inputs that do not match the plan's specs are an adapter bug: the
+    # executable's TypeError/ValueError propagates (never a silent recompile)
+    return exe(*args)
 
 
 def warm(plans: Iterable[MiningPlan]) -> Dict[str, int]:

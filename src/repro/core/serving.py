@@ -64,6 +64,7 @@ from . import events as events_lib
 from . import plan as plan_mod
 from .mining import (_OVERFLOW_MSG, LevelArrays, MinerConfig, _prune_level,
                      generate_candidates_arrays)
+from .episodes import window_f32
 from .streaming import _TAIL_SHORT_MSG, _ChainState, clean_chunk, suffix_cutoff
 
 
@@ -429,8 +430,8 @@ class StreamingCorpusMiner:
                             cs = cache[keys_of[i][r]]
                             pe[i, j] = cs.prev_end
                             pc[i, j] = cs.count
-                lo = np.full((bc, level - 1), cfg.t_low, np.float32)
-                hi = np.full((bc, level - 1), cfg.t_high, np.float32)
+                lo = np.full((bc, level - 1), window_f32(cfg.t_low))
+                hi = np.full((bc, level - 1), window_f32(cfg.t_high))
                 parts.append((sel, counting.count_corpus_tail_grouped(
                     self.tables, self.counts_dev,
                     old_counts_dev if oc is None else oc,

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .episodes import Episode
+from .episodes import Episode, in_window
 
 NEG = -jnp.inf
 
@@ -163,8 +163,8 @@ def count_fsm_scan(
         if n == 1:
             completes = valid & (e == sym[0]) & (t > prev_e)
         else:
-            win_ok = (bufs[n - 2] > NEG) & (t - bufs[n - 2] > lo[n - 2]) & (
-                t - bufs[n - 2] <= hi[n - 2])
+            win_ok = (bufs[n - 2] > NEG) & in_window(
+                t, bufs[n - 2], lo[n - 2], hi[n - 2])
             completes = valid & (e == sym[n - 1]) & jnp.any(win_ok)
 
         # non-completing updates for positions 0..n-2 (masked out on completion)
@@ -173,8 +173,8 @@ def count_fsm_scan(
             if j == 0:
                 add = valid & (e == sym[0]) & (t > prev_e)
             else:
-                ok = (bufs[j - 1] > NEG) & (t - bufs[j - 1] > lo[j - 1]) & (
-                    t - bufs[j - 1] <= hi[j - 1])
+                ok = (bufs[j - 1] > NEG) & in_window(
+                    t, bufs[j - 1], lo[j - 1], hi[j - 1])
                 add = valid & (e == sym[j]) & jnp.any(ok)
             add = add & ~completes
             new_bufs = jnp.where(

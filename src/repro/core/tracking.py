@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from . import compaction
+from .episodes import add_floor, sub_ceil
 
 NEG = -jnp.inf
 
@@ -111,8 +112,10 @@ def track_dense(
         t_prev = times_by_sym[i]
         t_next = times_by_sym[i + 1]
         # valid prev times s for next time t:  t - hi <= s < t - lo
-        lo_idx = jnp.searchsorted(t_prev, t_next - t_high[i], side="left")
-        hi_idx = jnp.searchsorted(t_prev, t_next - t_low[i], side="left")
+        lo_idx = jnp.searchsorted(t_prev, sub_ceil(t_next, t_high[i]),
+                                  side="left")
+        hi_idx = jnp.searchsorted(t_prev, sub_ceil(t_next, t_low[i]),
+                                  side="left")
         table = build_sparse_table(value)
         value = range_max(table, lo_idx.astype(jnp.int32), hi_idx.astype(jnp.int32))
         value = jnp.where(jnp.isfinite(t_next), value, NEG)
@@ -136,16 +139,16 @@ def track_dense(
 def _window_bounds_backward(t_prevsym, cur_t, lo, hi):
     """Events s of the *earlier* symbol valid for a later event at cur_t:
     lo < cur_t - s <= hi  <=>  s in [cur_t - hi, cur_t - lo)."""
-    wlo = jnp.searchsorted(t_prevsym, cur_t - hi, side="left")
-    whi = jnp.searchsorted(t_prevsym, cur_t - lo, side="left")
+    wlo = jnp.searchsorted(t_prevsym, sub_ceil(cur_t, hi), side="left")
+    whi = jnp.searchsorted(t_prevsym, sub_ceil(cur_t, lo), side="left")
     return wlo.astype(jnp.int32), whi.astype(jnp.int32)
 
 
 def _window_bounds_forward(t_nextsym, cur_t, lo, hi):
     """Events t of the *later* symbol valid after cur_t:
     lo < t - cur_t <= hi  <=>  t in (cur_t + lo, cur_t + hi]."""
-    wlo = jnp.searchsorted(t_nextsym, cur_t + lo, side="right")
-    whi = jnp.searchsorted(t_nextsym, cur_t + hi, side="right")
+    wlo = jnp.searchsorted(t_nextsym, add_floor(cur_t, lo), side="right")
+    whi = jnp.searchsorted(t_nextsym, add_floor(cur_t, hi), side="right")
     return wlo.astype(jnp.int32), whi.astype(jnp.int32)
 
 
@@ -262,10 +265,10 @@ class EngineConfig:
 
     cap_occ: Optional[int] = None
     max_window: int = 32
-    block_next: int = 256
-    block_prev: int = 256
+    block_next: int = 128
+    block_prev: int = 128
     window_tiles: int = 0
-    chunk: int = 8
+    chunk: int = 32
     interpret: Optional[bool] = None
     t_min: Optional[jax.Array] = None
 
@@ -482,14 +485,15 @@ class FaithfulEngine:
 
 def _pallas_tile_geometry(cap: int, cfg: EngineConfig):
     """(bn, bp, padded_cap) for the Pallas engines: the engine-policy block
-    clamp ([8, 256] — VMEM-friendly defaults) composed with the single
-    shared tiling rule in kernels/ops.py, so the per-level and fused
-    engines tile identically (their conservative window-truncation checks
-    must agree tile-for-tile)."""
+    clamp ([8, 256]) composed with the single shared tiling rule in
+    kernels/ops.py, so the per-level and fused engines tile identically
+    (their conservative window-truncation checks must agree tile-for-tile).
+    Tiles the chip cannot run raise there."""
     from ..kernels import ops  # deferred: core stays importable sans pallas
 
     return ops.tile_geometry(
-        cap, max(8, min(cfg.block_next, 256)), max(8, min(cfg.block_prev, 256)))
+        cap, max(8, min(cfg.block_next, 256)),
+        max(8, min(cfg.block_prev, 256)), interpret=cfg.interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -558,12 +562,12 @@ class FusedDensePallasEngine:
 
     Same dominance argument (and counts) as ``dense``/``dense_pallas``, but
     instead of ``B x (N-1)`` per-level kernel launches with HBM round-trips
-    between them, the whole batch runs on a ``(episodes, levels, next_tiles)``
-    grid: latest-start values stay in VMEM scratch across levels, the
-    per-(episode, level, tile) scan offsets are scalar-prefetched as one
-    precomputed table, and each next tile walks exactly the prev tiles its
-    constraint window spans (a dynamic in-kernel loop — no static quadratic
-    tile coverage). See kernels/episode_track.py and DESIGN.md §2.
+    between them, one launch walks every episode row through all of its
+    levels: latest-start values stay in VMEM scratch across levels, the
+    per-(episode, level, tile) scan offsets arrive as a per-row SMEM table,
+    and each next tile walks exactly the prev tiles its constraint window
+    spans (a dynamic in-kernel loop — no static quadratic tile coverage).
+    See kernels/episode_track.py and DESIGN.md §2.
 
     ``track_batch`` is the native entry point (dispatched by
     ``counting.count_batch_indexed``); ``track`` wraps it with a singleton
@@ -603,9 +607,9 @@ class FusedDensePallasEngine:
 
     def count_batch(self, times_by_sym, t_low, t_high, prev_end, prev_count,
                     cfg: EngineConfig):
-        """Single-launch count pipeline: tracking + in-VMEM count_scan_write
-        compaction + the greedy_scan_state fold, one kernel for the whole
-        batch (kernels/episode_track.py::count_batch_pallas, DESIGN.md §10).
+        """Single-launch count pipeline: tracking + the greedy_scan_state
+        fold over the keep-masked intervals, one kernel for the whole batch
+        (kernels/episode_track.py::count_batch_pallas, DESIGN.md §10).
 
         Returns ``(counts i32[B], end_out f32[B], n_superset i32[B],
         overflow bool[B])`` with the carried chain state included, exactly

@@ -13,8 +13,10 @@ This module is the single source of truth for that resolution:
 * :func:`bucket_key` — ``"kind:L{L}:N{pow2}:B{pow2}"`` buckets (capacity and
   batch rounded up to powers of two so nearby shapes share an entry).
 * :func:`resolve` — explicit caller overrides > checked-in
-  ``tuned_configs.json`` entry > :data:`DEFAULTS`. Pure function of its
-  arguments and the table file: deterministic, trace-time cheap (dict
+  ``tuned_configs.json`` entry > :data:`DEFAULTS`. A table records the
+  backend it was tuned on and is only served on that backend: tiles
+  wall-clocked in CPU interpret mode never reach the chip. Pure function of
+  its arguments and the table file: deterministic, trace-time cheap (dict
   lookup), safe to call from inside ``jit`` with static shapes.
 * :func:`candidate_configs` / :func:`model_time` — the tuning search space
   and the cost-model filter. ``model_time`` routes an analytic byte/flop
@@ -35,7 +37,9 @@ import dataclasses
 import functools
 import json
 import os
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+import jax
 
 from ..analysis import roofline
 # THE shared rounding rule: plan buckets and tuned-tile buckets round with
@@ -57,20 +61,21 @@ _SPAN_FRACTION = 0.05
 @dataclasses.dataclass(frozen=True)
 class TileConfig:
     """One bucket's tile/grid shape for a Pallas kernel launch."""
-    block_next: int = 256
-    block_prev: int = 256
+    block_next: int = 128
+    block_prev: int = 128
     window_tiles: int = 0
-    chunk: int = 8
+    chunk: int = 32
 
     def asdict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
 
 
-#: Fallbacks when no tuned entry exists — the pre-autotune constants, so a
-#: missing/deleted tuned_configs.json reproduces legacy behavior exactly.
+#: Fallbacks when no tuned entry serves the backend: one 128-lane tile per
+#: side (the smallest the chip runs) and 32-row greedy chunks — compiled
+#: for a v5e at the smoke's widths in tests/test_tpu_compile.py.
 DEFAULTS: Dict[str, TileConfig] = {
-    "track": TileConfig(block_next=256, block_prev=256, window_tiles=0, chunk=8),
-    "count": TileConfig(block_next=256, block_prev=256, window_tiles=0, chunk=8),
+    "track": TileConfig(block_next=128, block_prev=128, window_tiles=0, chunk=8),
+    "count": TileConfig(block_next=128, block_prev=128, window_tiles=0, chunk=32),
 }
 
 
@@ -94,19 +99,27 @@ def bucket_key(kind: str, levels: int, cap: int, batch: int) -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def _load_table(path: str) -> Dict[str, Dict[str, int]]:
+def _load_table(path: str) -> Tuple[Optional[str], Dict[str, Dict[str, int]]]:
     try:
         with open(path) as f:
             raw = json.load(f)
     except (OSError, ValueError):
-        return {}
-    table = raw.get("configs", raw) if isinstance(raw, dict) else {}
-    return {k: v for k, v in table.items() if isinstance(v, dict)}
+        return None, {}
+    if not isinstance(raw, dict):
+        return None, {}
+    table = raw.get("configs", raw)
+    backend = raw.get("backend") if "configs" in raw else None
+    return backend, {k: v for k, v in table.items() if isinstance(v, dict)}
 
 
 def load_table(path: Optional[str] = None) -> Dict[str, Dict[str, int]]:
     """The checked-in tuned table ({} when missing/invalid — never raises)."""
-    return dict(_load_table(path or _CONFIG_PATH))
+    return dict(_load_table(path or _CONFIG_PATH)[1])
+
+
+def table_backend(path: Optional[str] = None) -> Optional[str]:
+    """Backend the table was tuned on (None: untagged, served everywhere)."""
+    return _load_table(path or _CONFIG_PATH)[0]
 
 
 def clear_cache() -> None:
@@ -125,16 +138,21 @@ def resolve(
     window_tiles: Optional[int] = None,
     chunk: Optional[int] = None,
     path: Optional[str] = None,
+    backend: Optional[str] = None,
 ) -> TileConfig:
-    """Tile config for a problem bucket.
+    """Tile config for a problem bucket on ``backend`` (default: JAX's).
 
     Precedence per field: explicit (non-None) caller override, then the
-    tuned-table entry for :func:`bucket_key`, then :data:`DEFAULTS[kind]`.
+    tuned-table entry for :func:`bucket_key` — only when the table was
+    tuned on ``backend`` (or is untagged) — then :data:`DEFAULTS[kind]`.
     Deterministic: same arguments + same table file => same answer.
     """
     base = DEFAULTS[kind] if kind in DEFAULTS else None
     key = bucket_key(kind, levels, cap, batch)   # validates kind
-    entry = _load_table(path or _CONFIG_PATH).get(key, {})
+    tuned_on, table = _load_table(path or _CONFIG_PATH)
+    if backend is None:
+        backend = jax.default_backend()
+    entry = table.get(key, {}) if tuned_on in (None, backend) else {}
 
     def pick(override, field):
         if override is not None:
@@ -156,11 +174,15 @@ def resolve(
 
 def candidate_configs(kind: str, cap: int, batch: int) -> List[TileConfig]:
     """Deterministic candidate grid for one bucket (exact-tiling configs
-    only; ``window_tiles`` stays 0 — exactness is non-negotiable)."""
+    only; ``window_tiles`` stays 0 — exactness is non-negotiable). On the
+    chip only 128-lane multiples compile."""
     if kind not in DEFAULTS:
         raise ValueError(
             f"unknown kernel kind {kind!r}; expected one of {sorted(DEFAULTS)}")
-    blocks = [b for b in (8, 16, 32, 64, 128, 256) if b <= cap]
+    if jax.default_backend() == "tpu":
+        blocks = [128, 256]
+    else:
+        blocks = [b for b in (8, 16, 32, 64, 128, 256) if b <= cap]
     chunks = [c for c in (8, 16, 32) if c <= max(batch, 8)]
     out = []
     for b in blocks:
@@ -206,7 +228,7 @@ def model_time(
     r = roofline.analyze(
         arch="v5e", shape=f"{kind}:L{levels}:N{cap}:B{batch}",
         mesh_name="single", chips=1, cost=cost, coll={"total": 0.0},
-        model_flops=0.0)
+        model_flops=0.0, device_kind=roofline.V5E)
     return max(r.t_compute, r.t_memory) + cost["grid_steps"] * _STEP_OVERHEAD_S
 
 

@@ -1,459 +1,320 @@
-"""Pallas TPU kernel for one level of parallel local tracking.
+"""Pallas TPU kernel for parallel local tracking and non-overlapped counting.
 
 This is the compute hot-spot the paper optimizes (Algorithm 2): for every
 event ``t`` of the next episode symbol, combine (max-reduce) the
 latest-start values of all previous-symbol events ``s`` inside the
-inter-event window ``t - hi <= s < t - lo``.
+inter-event window ``lo < t - s <= hi`` (exact, ``core.episodes``).
 
-TPU adaptation (DESIGN.md §2): instead of one divergent scanning thread per
-event (the CUDA formulation), the time axis is tiled into VMEM blocks. The
-grid is ``(next_tiles, window_tiles)``; for next-tile ``i`` the inner
-dimension walks the ``window_tiles`` previous-symbol tiles that can overlap
-its constraint window, starting at a *scalar-prefetched* tile offset
-(computed with searchsorted in ops.py — the paper's per-type index made
-block-level). Inside the kernel a (BN, BP) broadcast compare + row max
-replaces the divergent scan; max-accumulation is idempotent so clamped /
-duplicated boundary tiles are harmless.
+TPU adaptation (DESIGN.md §2, §10). One kernel body, :func:`_rows_kernel`,
+serves every Pallas path. Each grid step owns ONE episode row and walks all
+of its levels with the latest-start vector resident in a ``(2, K, W)`` VMEM
+ping-pong scratch (a row of ``cap`` events is laid out as ``K = cap / W``
+sublane rows of ``W`` lanes, ``W`` = 128 on the chip):
 
-VMEM per grid step: BN + 2*BP + BN*BP fp32 ≈ 0.27 MB at BN=BP=256 — far
-under the ~16 MB/core budget, leaving room for double buffering.
+* the per-(row, level, next-tile) first prev tile and exact tile count come
+  from a scan table computed outside the kernel (``ops.window_scan_table``
+  — the paper's per-type index made block-level) and arrive as a per-row
+  SMEM block, so every read is a scalar-offset ``pl.ds`` slice of a ref —
+  no vector gathers;
+* for a next sub-row of ``W`` events, its exact window edges
+  (``core.episodes.sub_ceil``) are transposed once into columns, and each
+  prev sub-row it must scan is a ``(1, W)`` row: the window test is a
+  ``(W, W)`` broadcast compare + running max, independent of ``cap`` — the
+  per-step footprint is bounded by the tile, not by the stream;
+* next tiles past the last one with a non-empty scan are skipped (their
+  values are the ``-inf`` prefill).
 
-Fused batched variant (DESIGN.md §2): ``track_batch_pallas`` runs an entire
-candidate batch's multi-level tracking in ONE launch. Grid = ``(episodes,
-levels, next_tiles)``; the latest-start vector never leaves VMEM between
-levels (a ``(2, cap)`` double-buffered scratch, flipped per level), the
-per-(episode, level, next-tile) first-prev-tile offsets and scan lengths
-are scalar-prefetched as one precomputed table, and the window walk is a
-*dynamic* ``fori_loop`` over exactly the prev tiles each next tile's
-constraint window spans — no static quadratic tile coverage at all.
+Two epilogues share the walk:
 
-Single-launch count pipeline (DESIGN.md §10): ``count_batch_pallas`` goes
-further — tracking, the paper's §IV-D count_scan_write compaction, AND the
-greedy non-overlap scheduler all run inside ONE kernel. Grid =
-``(batch_chunks,)``: each grid step owns a whole chunk of episodes, walks
-every level with vectorized whole-chunk tile gathers (occurrence intervals
-never leave VMEM), prefix-scans the valid flags and compacts the surviving
-``(start, end)`` intervals in-register (the scatter-write inverted into a
-searchsorted gather — TPU/XLA-friendly either way), then folds the exact
-``greedy_scan_state`` recurrence over ONLY the compacted prefix (a dynamic
-``fori_loop`` bounded by the per-chunk max valid count, not ``cap``). The
-kernel emits final counts plus the carried ``(prev_end, count)`` chain
-state, so the streaming stitch works unchanged.
+* ``count=False`` (``track_batch_pallas``): the final-level latest-start
+  row goes to HBM, with the tracked superset size.
+* ``count=True`` (``count_batch_pallas``): the single-launch count pipeline.
+  Each row's final latest-start row and end-time row are parked in a
+  ``(K, R, W)`` chunk scratch; the chunk's last row then runs the exact
+  ``greedy_scan_state`` recurrence (take iff ``start > prev_end``, strict —
+  ties rejected) for all ``R`` rows at once, rows on lanes, events walked in
+  end order over ``(W, R)`` transposed blocks. Invalid intervals carry a
+  ``-inf`` start, which the strict test never takes, so the greedy over the
+  uncompacted keep-masked intervals takes exactly the intervals the paper's
+  §IV-D compaction + greedy would — no prefix sum, no searchsorted, no
+  gather. Only O(B) scalars leave the kernel: counts, the carried
+  ``(prev_end, count)`` chain state, and superset sizes.
+
+VMEM per grid step: the row's times block ``(N, K, W)`` (double-buffered),
+the ``(2, K, W)`` ping-pong, the count epilogue's ``2 * R * cap`` chunk
+scratch and a few ``(W, W)`` compare temporaries — :func:`vmem_bytes`.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.episodes import sub_ceil
+
 NEG = -jnp.inf
 
-
-def _track_level_kernel(
-    # scalar-prefetch operands
-    start_tile_ref,     # i32[next_tiles] first prev-tile per next-tile
-    window_ref,         # f32[2] = (t_low, t_high)
-    # array operands
-    t_next_ref,         # f32[BN]   block of next-symbol times
-    t_prev_ref,         # f32[BP]   block of prev-symbol times
-    v_prev_ref,         # f32[BP]   block of prev-symbol latest-start values
-    # outputs
-    v_next_ref,         # f32[BN]   accumulated latest-start values
-):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        v_next_ref[...] = jnp.full_like(v_next_ref, NEG)
-
-    t_lo = window_ref[0]
-    t_hi = window_ref[1]
-    t_next = t_next_ref[...]                       # [BN]
-    t_prev = t_prev_ref[...]                       # [BP]
-    v_prev = v_prev_ref[...]                       # [BP]
-
-    # window: t - hi <= s < t - lo   (paper: lo < t - s <= hi)
-    ok = (t_prev[None, :] >= t_next[:, None] - t_hi) & (
-        t_prev[None, :] < t_next[:, None] - t_lo)          # [BN, BP]
-    contrib = jnp.max(jnp.where(ok, v_prev[None, :], NEG), axis=1)
-    v_next_ref[...] = jnp.maximum(v_next_ref[...], contrib)
+#: Lane width of the row layout on the chip; interpret mode may use any
+#: common divisor of the tiles (see ``ops.tile_geometry``).
+LANES = 128
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_next", "block_prev", "window_tiles", "interpret"),
-)
-def track_level_pallas(
-    t_prev: jax.Array,      # f32[cap] sorted, +inf padded
-    v_prev: jax.Array,      # f32[cap] latest-start values (-inf pad)
-    t_next: jax.Array,      # f32[cap] sorted, +inf padded
-    t_low,
-    t_high,
-    *,
-    block_next: int = 256,
-    block_prev: int = 256,
-    window_tiles: int = 0,   # 0 => cover all prev tiles (always exact)
-    interpret: bool = False,
-) -> jax.Array:
-    """One tracking level. Exact iff the constraint window of every next
-    block fits in ``window_tiles`` prev blocks (0 = all blocks, always
-    exact; ops.py computes a tight bound)."""
-    cap = t_prev.shape[0]
-    if t_next.shape[0] != cap or v_prev.shape[0] != cap:
-        raise ValueError("equal-capacity level arrays required")
-    bn = min(block_next, cap)
-    bp = min(block_prev, cap)
-    if cap % bn or cap % bp:
-        raise ValueError(f"cap={cap} must be a multiple of block sizes {bn},{bp}")
-    next_tiles = cap // bn
-    prev_tiles = cap // bp
-    wt = prev_tiles if window_tiles == 0 else min(window_tiles, prev_tiles)
+def vmem_bytes(levels: int, pcap: int, lanes: int, rows: int, *,
+               count: bool, seeded: bool = False) -> int:
+    """Per-grid-step VMEM footprint of :func:`_rows_kernel` (bytes).
 
-    # first prev tile whose block may intersect the earliest window of the
-    # next tile:   first s >= min_t(t_next tile) - t_high
-    tile_min = t_next.reshape(next_tiles, bn)[:, 0]
-    start_idx = jnp.searchsorted(t_prev, tile_min - jnp.float32(t_high), side="left")
-    start_tile = jnp.clip(
-        (start_idx // bp).astype(jnp.int32), 0, jnp.int32(max(prev_tiles - wt, 0)))
-    window = jnp.asarray([t_low, t_high], jnp.float32)
-
-    grid = (next_tiles, wt)
-    kernel = pl.pallas_call(
-        _track_level_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bn,), lambda i, j, st, w: (i,)),
-                pl.BlockSpec((bp,), lambda i, j, st, w: (st[i] + j,)),
-                pl.BlockSpec((bp,), lambda i, j, st, w: (st[i] + j,)),
-            ],
-            out_specs=pl.BlockSpec((bn,), lambda i, j, st, w: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((cap,), jnp.float32),
-        interpret=interpret,
-    )
-    return kernel(start_tile, window, t_next, t_prev, v_prev)
-
-
-# ---------------------------------------------------------------------------
-# Fused batched multi-level kernel
-# ---------------------------------------------------------------------------
-
-
-def _track_batch_kernel(
-    # scalar-prefetch operands (flattened tables; shapes are SMEM-friendly 1-D)
-    start_ref,          # i32[B*L*NT] first prev tile per (episode, level, next-tile)
-    num_ref,            # i32[B*L*NT] prev tiles to scan per (episode, level, next-tile)
-    t_low_ref,          # f32[B*L] per-episode, per-level window low
-    t_high_ref,         # f32[B*L] per-episode, per-level window high
-    # array operands
-    t_next_ref,         # f32[1, 1, BN]  next-symbol tile of the current level
-    t_prev_ref,         # f32[1, 1, cap] full prev-symbol row (revisited across tiles)
-    # outputs
-    v_out_ref,          # f32[1, BN]  final-level latest-start values
-    nsup_ref,           # i32[1, 1]   per-episode superset-size accumulator
-    # scratch
-    vbuf,               # f32[2, cap] level-ping-pong latest-start buffer
-    *,
-    levels: int,
-    next_tiles: int,
-    block_next: int,
-    block_prev: int,
-):
-    b = pl.program_id(0)
-    l = pl.program_id(1)
-    i = pl.program_id(2)
-    p = jax.lax.rem(l, 2)
-    bn, bp = block_next, block_prev
-
-    t_next = t_next_ref[0, 0, :]                               # [BN]
-    t_lo = t_low_ref[b * levels + l]
-    t_hi = t_high_ref[b * levels + l]
-    flat = (b * levels + l) * next_tiles + i
-    st = start_ref[flat]
-    num = num_ref[flat]
-    is_first_level = l == 0
-
-    def scan_tile(j, acc):
-        off = (st + j) * bp
-        tp = t_prev_ref[0, 0, pl.ds(off, bp)]                  # [BP]
-        # level 0 seeds latest-start = the first-symbol event time itself;
-        # later levels read the previous level's values from VMEM scratch.
-        vp = jnp.where(is_first_level,
-                       jnp.where(jnp.isfinite(tp), tp, NEG),
-                       vbuf[p, pl.ds(off, bp)])
-        ok = (tp[None, :] >= t_next[:, None] - t_hi) & (
-            tp[None, :] < t_next[:, None] - t_lo)              # [BN, BP]
-        return jnp.maximum(
-            acc, jnp.max(jnp.where(ok, vp[None, :], NEG), axis=1))
-
-    acc = jax.lax.fori_loop(
-        0, num, scan_tile, jnp.full((bn,), NEG, jnp.float32))
-    acc = jnp.where(jnp.isfinite(t_next), acc, NEG)
-    vbuf[1 - p, pl.ds(i * bn, bn)] = acc
-    # every visit writes; the grid is sequential so the last level's values
-    # are what lands in HBM for this (episode, tile) block.
-    v_out_ref[0, :] = acc
-
-    # superset size: count of reachable end events, accumulated per level in
-    # the revisited (1, 1) output block; seeded with the level-0 event count.
-    n0 = jnp.sum(jnp.isfinite(t_prev_ref[0, 0, :])).astype(jnp.int32)
-    seed = jnp.where(is_first_level & (i == 0), n0, nsup_ref[0, 0])
-    nsup_ref[0, 0] = seed + jnp.sum(acc > NEG).astype(jnp.int32)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_next", "block_prev", "interpret"),
-)
-def track_batch_pallas(
-    times_by_sym: jax.Array,    # f32[B, N, cap] sorted rows, +inf padded
-    t_low: jax.Array,           # f32[B, N-1]
-    t_high: jax.Array,          # f32[B, N-1]
-    start_tile: jax.Array,      # i32[B, N-1, next_tiles] first prev tile to scan
-    num_tiles: jax.Array,       # i32[B, N-1, next_tiles] prev tiles to scan
-    *,
-    block_next: int = 256,
-    block_prev: int = 256,
-    interpret: bool = False,
-) -> tuple:
-    """Whole-batch multi-level tracking in one fused launch.
-
-    Returns ``(starts f32[B, cap], n_superset i32[B])``: the final-level
-    latest-start values (before end-validity masking) and the per-episode
-    tracked superset size. ``start_tile``/``num_tiles`` come from
-    ``ops.window_scan_table`` — exact per-tile spans, so the kernel is exact
-    whenever the table is uncapped (``ops`` flags any capping).
-
-    The batch dimension of the grid is just "independent rows": a corpus of
-    streams rides it by folding ``(stream, episode)`` into ``B`` — the fold
-    lives in ``ops.track_batch`` (per-row scan tables are row-independent,
-    so the flattened layout is fold-invariant), not here.
+    Blocks are double-buffered by the pipeline; scratch is single. The
+    ``(W, R)`` greedy transposes pad their lane dim to 128.
     """
-    batch, n, cap = times_by_sym.shape
-    levels = n - 1
-    if levels < 1:
-        raise ValueError("need at least a 2-symbol episode for the kernel")
-    bn = min(block_next, cap)
-    bp = min(block_prev, cap)
-    if cap % bn or cap % bp:
-        raise ValueError(f"cap={cap} must be a multiple of block sizes {bn},{bp}")
-    next_tiles = cap // bn
-
-    kernel = pl.pallas_call(
-        functools.partial(
-            _track_batch_kernel, levels=levels, next_tiles=next_tiles,
-            block_next=bn, block_prev=bp),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(batch, levels, next_tiles),
-            in_specs=[
-                pl.BlockSpec((1, 1, bn), lambda b, l, i, *_: (b, l + 1, i)),
-                pl.BlockSpec((1, 1, cap), lambda b, l, i, *_: (b, l, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bn), lambda b, l, i, *_: (b, i)),
-                pl.BlockSpec((1, 1), lambda b, l, i, *_: (b, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((2, cap), jnp.float32)],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, cap), jnp.float32),
-            jax.ShapeDtypeStruct((batch, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-    starts, nsup = kernel(
-        start_tile.reshape(-1), num_tiles.reshape(-1),
-        t_low.reshape(-1).astype(jnp.float32),
-        t_high.reshape(-1).astype(jnp.float32),
-        times_by_sym, times_by_sym)
-    return starts, nsup[:, 0]
+    n = levels + 1
+    blocks = 2 * 4 * n * pcap                    # times block
+    if seeded:
+        blocks += 2 * 4 * pcap                   # seed values block
+    if not count:
+        blocks += 2 * 4 * pcap                   # final-level values out
+    scratch = 2 * 4 * pcap                       # latest-start ping-pong
+    scratch += 6 * 4 * lanes * lanes             # window compare temporaries
+    if count:
+        scratch += 2 * 4 * rows * pcap           # chunk starts + ends
+        scratch += 2 * 4 * lanes * max(rows, 128)
+    return blocks + scratch
 
 
-# ---------------------------------------------------------------------------
-# Single-launch count pipeline: tracking + compaction + greedy in one kernel
-# ---------------------------------------------------------------------------
+def _rows_kernel(*refs, levels: int, next_tiles: int, sub_next: int,
+                 sub_prev: int, lanes: int, count: bool, seeded: bool):
+    it = iter(refs)
+    times_ref = next(it)          # f32 (1, N, K, W) one episode row
+    # i32 SMEM (1, 1, T) scan table, level-major: start[L, NT] | num[L, NT]
+    # | last[L] (next tiles to walk) | n_end (finite end events)
+    tab_ref = next(it)
+    win_ref = next(it)            # f32 SMEM (1, 1, 2L) window lows | highs
+    seed_ref = next(it) if seeded else None      # f32 (1, K, W)
+    if count:
+        pend_ref = next(it)       # f32 (1, 1, R) carried prev_end
+        pcnt_ref = next(it)       # i32 (1, 1, R) carried count
+        cnt_ref = next(it)        # i32 (1, 1, R)
+        end_ref = next(it)        # f32 (1, 1, R)
+    else:
+        v_ref = next(it)          # f32 (1, K, W) final-level values
+    nsup_ref = next(it)           # i32 SMEM (1, 1, R) superset sizes
+    vbuf = next(it)               # f32 (2, K, W) level ping-pong
+    if count:
+        s_all = next(it)          # f32 (K, R, W) chunk latest starts
+        e_all = next(it)          # f32 (K, R, W) chunk end times
+        s_blk = next(it)          # f32 (W, R) transposed greedy block
+        e_blk = next(it)          # f32 (W, R)
+        kmax = next(it)           # i32 SMEM (1,) chunk max end count
 
+    w = lanes
+    r = pl.program_id(1)
 
-def _count_batch_kernel(
-    # array operands (one grid step owns a whole chunk of R episodes)
-    times_ref,          # f32[R, N, cap]   sorted rows, +inf padded
-    t_low_ref,          # f32[R, L]        per-episode, per-level window low
-    t_high_ref,         # f32[R, L]        per-episode, per-level window high
-    start_ref,          # i32[R, L, NT]    first prev tile per next-tile
-    num_ref,            # i32[R, L, NT]    prev tiles to scan per next-tile
-    pend_ref,           # f32[R, 1]        carried greedy prev_end
-    pcnt_ref,           # i32[R, 1]        carried greedy count
-    # outputs
-    count_ref,          # i32[R, 1]        final non-overlapped counts
-    end_ref,            # f32[R, 1]        carried-out prev_end
-    nsup_ref,           # i32[R, 1]        tracked superset sizes
-    *,
-    levels: int,
-    block_next: int,
-    block_prev: int,
-    next_tiles: int,
-):
-    R = times_ref.shape[0]
-    cap = times_ref.shape[2]
-    bn, bp = block_next, block_prev
+    def column(row):                      # (1, W) -> (W, W), [n, q] = row[n]
+        return jnp.broadcast_to(row, (w, w)).T
 
-    # --- tracking: the _track_batch_kernel recurrence, vectorized over the
-    # whole chunk. The latest-start vector v lives in registers/VMEM for the
-    # entire level walk — it is never written back to HBM.
-    t0 = times_ref[:, 0, :]
-    v = jnp.where(jnp.isfinite(t0), t0, NEG)
-    nsup = jnp.sum(jnp.isfinite(t0), axis=-1).astype(jnp.int32)
-    bidx = jnp.arange(bp, dtype=jnp.int32)
+    nt = next_tiles
+    t0 = times_ref[0, 0]
+    if seeded:
+        vbuf[0] = seed_ref[0]
+    else:
+        vbuf[0] = jnp.where(jnp.isfinite(t0), t0, NEG)
+    nsup = jnp.sum(jnp.isfinite(t0).astype(jnp.int32))
+
     for l in range(levels):
-        t_next = times_ref[:, l + 1, :]
-        tn = t_next.reshape(R, next_tiles, bn)
-        st = start_ref[:, l, :]
-        num = num_ref[:, l, :]
-        t_lo = t_low_ref[:, l][:, None, None, None]
-        t_hi = t_high_ref[:, l][:, None, None, None]
-        max_num = jnp.max(num)
-        t_prev = times_ref[:, l, :]
-        vprev = v
+        src, dst = l % 2, 1 - l % 2
+        vbuf[dst] = jnp.full(vbuf.shape[1:], NEG, jnp.float32)
+        t_lo = win_ref[0, 0, l]
+        t_hi = win_ref[0, 0, levels + l]
 
-        def scan_tile(j, acc, st=st, num=num, t_prev=t_prev, vprev=vprev,
-                      tn=tn, t_lo=t_lo, t_hi=t_hi):
-            live = j < num                                     # [R, NT]
-            idx = (st + j)[:, :, None] * bp + bidx[None, None, :]
-            flat = jnp.minimum(idx, cap - 1).reshape(R, -1)
-            tp = jnp.take_along_axis(t_prev, flat, axis=1).reshape(
-                R, next_tiles, bp)
-            vp = jnp.take_along_axis(vprev, flat, axis=1).reshape(
-                R, next_tiles, bp)
-            ok = (tp[:, :, None, :] >= tn[..., None] - t_hi) & (
-                tp[:, :, None, :] < tn[..., None] - t_lo)      # [R, NT, BN, BP]
-            contrib = jnp.max(jnp.where(ok, vp[:, :, None, :], NEG), axis=-1)
-            return jnp.maximum(acc, jnp.where(live[:, :, None], contrib, NEG))
+        def next_tile(i, carry, l=l, src=src, dst=dst, t_lo=t_lo, t_hi=t_hi):
+            st = tab_ref[0, 0, l * nt + i]
+            num = tab_ref[0, 0, (levels + l) * nt + i]
+            for a in range(sub_next):
+                kn = i * sub_next + a
+                tn = times_ref[0, l + 1, pl.ds(kn, 1), :]          # (1, W)
+                # exact window edges per next event, as [n, q] = edge[n]
+                lo_b = column(sub_ceil(tn, t_hi))
+                hi_b = column(sub_ceil(tn, t_lo))
 
-        acc = jax.lax.fori_loop(
-            0, max_num, scan_tile,
-            jnp.full((R, next_tiles, bn), NEG, jnp.float32))
-        v = jnp.where(jnp.isfinite(t_next), acc.reshape(R, cap), NEG)
-        nsup = nsup + jnp.sum(v > NEG, axis=-1).astype(jnp.int32)
+                def prev_tile(j, acc, lo_b=lo_b, hi_b=hi_b):
+                    for b in range(sub_prev):
+                        kp = (st + j) * sub_prev + b
+                        tp = times_ref[0, l, pl.ds(kp, 1), :]      # (1, W)
+                        vp = vbuf[src, pl.ds(kp, 1), :]
+                        ok = (tp >= lo_b) & (tp < hi_b)            # (W, W)
+                        acc = jnp.maximum(acc, jnp.where(ok, vp, NEG))
+                    return acc
 
-    # --- in-VMEM count_scan_write compaction (paper §IV-D): prefix-scan the
-    # keep flags, then invert the scatter-write into a gather — row r's k-th
-    # surviving interval sits at searchsorted(csum[r], k+1). Bit-identical to
-    # the scatter formulation and far cheaper on both XLA-CPU and TPU.
-    ends = times_ref[:, levels, :]
-    valid = (v > NEG) & jnp.isfinite(ends)
-    keep = valid.astype(jnp.int32)
-    csum = jnp.cumsum(keep, axis=1)
-    targets = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)[0] + 1
-    src = jax.vmap(lambda c: jnp.searchsorted(c, targets, side="left"))(csum)
-    src_c = jnp.minimum(src, cap - 1)
-    live = src < cap
-    sT = jnp.where(live, jnp.take_along_axis(v, src_c, axis=1), NEG).T
-    eT = jnp.where(live, jnp.take_along_axis(ends, src_c, axis=1), jnp.inf).T
-    m = csum[:, -1]                                            # valid per row
+                acc = jax.lax.fori_loop(
+                    0, num, prev_tile, jnp.full((w, w), NEG, jnp.float32))
+                best = jnp.max(acc.T, axis=0, keepdims=True)       # (1, W)
+                vbuf[dst, pl.ds(kn, 1), :] = jnp.where(
+                    jnp.isfinite(tn), best, NEG)
+            return carry
 
-    # --- greedy non-overlap scheduling: exactly scheduling.greedy_scan_state
-    # (take iff valid & start > prev_end, strict — ties rejected), folded
-    # over ONLY the compacted prefix: max(m) trips instead of cap.
-    def step(j, carry):
-        prev_e, cnt = carry
-        s_j = jax.lax.dynamic_slice_in_dim(sT, j, 1, axis=0)[0]
-        e_j = jax.lax.dynamic_slice_in_dim(eT, j, 1, axis=0)[0]
-        take = (j < m) & (s_j > prev_e)
-        return (jnp.where(take, e_j, prev_e), cnt + take.astype(jnp.int32))
+        last = tab_ref[0, 0, 2 * levels * nt + l]
+        jax.lax.fori_loop(0, last, next_tile, 0)
+        nsup = nsup + jnp.sum((vbuf[dst] > NEG).astype(jnp.int32))
 
-    prev_e, cnt = jax.lax.fori_loop(
-        0, jnp.max(m), step,
-        (pend_ref[:, 0], jnp.zeros((R,), jnp.int32)))
-    count_ref[:, 0] = pcnt_ref[:, 0] + cnt
-    end_ref[:, 0] = prev_e
-    nsup_ref[:, 0] = nsup
+    nsup_ref[0, 0, r] = nsup
+    v_fin = vbuf[levels % 2]
+    if not count:
+        v_ref[0] = v_fin
+        return
+
+    # --- count epilogue: park this row, then the chunk's last row folds the
+    # greedy for every row of the chunk at once (rows on lanes).
+    rows = s_all.shape[1]
+    s_all[:, pl.ds(r, 1), :] = v_fin[:, None, :]
+    e_all[:, pl.ds(r, 1), :] = times_ref[0, levels][:, None, :]
+    n_end = tab_ref[0, 0, 2 * levels * nt + levels]
+
+    @pl.when(r == 0)
+    def _():
+        kmax[0] = 0
+
+    kmax[0] = jnp.maximum(kmax[0], n_end)
+
+    @pl.when(r == rows - 1)
+    def _():
+        def block(k, carry):
+            s_blk[...] = s_all[k].T                                # (W, R)
+            e_blk[...] = e_all[k].T
+
+            def step(j, carry):
+                prev_e, cnt = carry
+                s = s_blk[pl.ds(j, 1), :]
+                take = s > prev_e
+                return (jnp.where(take, e_blk[pl.ds(j, 1), :], prev_e),
+                        cnt + take.astype(jnp.int32))
+
+            return jax.lax.fori_loop(0, w, step, carry)
+
+        n_blocks = (kmax[0] + w - 1) // w
+        prev_e, cnt = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (pend_ref[0], jnp.zeros((1, rows), jnp.int32)))
+        cnt_ref[0] = pcnt_ref[0] + cnt
+        end_ref[0] = prev_e
+
+
+def _call(times4, tab, win, extra, *, block_next: int, block_prev: int,
+          rows: int, count: bool, seeded: bool, interpret: bool,
+          vmem_limit: Optional[int]):
+    batch, n, k, w = times4.shape
+    levels = n - 1
+    next_tiles = (k * w) // block_next
+    nchunks = batch // rows
+    kernel = functools.partial(
+        _rows_kernel, levels=levels, next_tiles=next_tiles,
+        sub_next=block_next // w, sub_prev=block_prev // w, lanes=w,
+        count=count, seeded=seeded)
+    row = lambda c, r: (c * rows + r, 0, 0)              # noqa: E731
+    chunk = lambda c, r: (c, 0, 0)                        # noqa: E731
+    smem = pltpu.MemorySpace.SMEM
+    in_specs = [
+        pl.BlockSpec((1, n, k, w), lambda c, r: (c * rows + r, 0, 0, 0)),
+        pl.BlockSpec((1, 1, tab.shape[-1]), row, memory_space=smem),
+        pl.BlockSpec((1, 1, win.shape[-1]), row, memory_space=smem),
+    ]
+    if seeded:
+        in_specs.append(pl.BlockSpec((1, k, w), row))
+    nsup_spec = pl.BlockSpec((1, 1, rows), chunk, memory_space=smem)
+    nsup_shape = jax.ShapeDtypeStruct((nchunks, 1, rows), jnp.int32)
+    scratch = [pltpu.VMEM((2, k, w), jnp.float32)]
+    if count:
+        in_specs += [pl.BlockSpec((1, 1, rows), chunk)] * 2
+        out_specs = [pl.BlockSpec((1, 1, rows), chunk)] * 2 + [nsup_spec]
+        out_shape = [jax.ShapeDtypeStruct((nchunks, 1, rows), jnp.int32),
+                     jax.ShapeDtypeStruct((nchunks, 1, rows), jnp.float32),
+                     nsup_shape]
+        scratch += [pltpu.VMEM((k, rows, w), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((w, rows), jnp.float32)] * 2
+        scratch += [pltpu.SMEM((1,), jnp.int32)]
+    else:
+        out_specs = [pl.BlockSpec((1, k, w), row), nsup_spec]
+        out_shape = [jax.ShapeDtypeStruct((batch, k, w), jnp.float32),
+                     nsup_shape]
+    return pl.pallas_call(
+        kernel,
+        grid=(nchunks, rows),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name="count_batch" if count else "track_batch",
+    )(times4, tab, win, *extra)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_next", "block_prev", "chunk", "interpret"),
-)
+    static_argnames=("block_next", "block_prev", "interpret", "vmem_limit"))
+def track_batch_pallas(
+    times4: jax.Array,          # f32[B, N, K, W] sorted rows, +inf padded
+    tab: jax.Array,             # i32[B, 1, T] scan tables (_rows_kernel)
+    win: jax.Array,             # f32[B, 1, 2(N-1)] window lows | highs
+    seed: Optional[jax.Array] = None,   # f32[B, K, W] level-0 values
+    *,
+    block_next: int,
+    block_prev: int,
+    interpret: bool = False,
+    vmem_limit: Optional[int] = None,
+) -> tuple:
+    """Whole-batch multi-level tracking in one launch.
+
+    Returns ``(starts f32[B, K, W], n_superset i32[B])``: the final-level
+    latest-start values (masked to finite end events) and the per-row
+    tracked superset size. ``seed`` replaces the default level-0 values
+    (each first-symbol event's own time) — the per-level API passes the
+    previous level's values through it.
+    """
+    batch = times4.shape[0]
+    extra = () if seed is None else (seed,)
+    v, nsup = _call(times4, tab, win, extra, block_next=block_next,
+                    block_prev=block_prev, rows=1, count=False,
+                    seeded=seed is not None, interpret=interpret,
+                    vmem_limit=vmem_limit)
+    return v, nsup.reshape(batch)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_next", "block_prev", "rows", "interpret",
+                     "vmem_limit"))
 def count_batch_pallas(
-    times_by_sym: jax.Array,    # f32[B, N, cap] sorted rows, +inf padded
-    t_low: jax.Array,           # f32[B, N-1]
-    t_high: jax.Array,          # f32[B, N-1]
-    start_tile: jax.Array,      # i32[B, N-1, next_tiles]
-    num_tiles: jax.Array,       # i32[B, N-1, next_tiles]
+    times4: jax.Array,          # f32[B, N, K, W] sorted rows, +inf padded
+    tab: jax.Array,             # i32[B, 1, T] scan tables (_rows_kernel)
+    win: jax.Array,             # f32[B, 1, 2(N-1)] window lows | highs
     prev_end: jax.Array,        # f32[B] carried greedy prev_end
     prev_count: jax.Array,      # i32[B] carried greedy count
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
-    chunk: int = 8,
+    block_next: int,
+    block_prev: int,
+    rows: int,
     interpret: bool = False,
+    vmem_limit: Optional[int] = None,
 ) -> tuple:
-    """Whole-batch tracking + compaction + greedy counting, ONE launch.
+    """Whole-batch tracking + greedy counting, ONE launch.
 
-    Returns ``(counts i32[B], end_out f32[B], n_superset i32[B])``: the
-    final non-overlapped counts (carry-in ``prev_count`` included), the
-    carried-out greedy ``prev_end`` state, and the tracked superset sizes.
-    Occurrence intervals never round-trip to HBM — only these O(B) scalars
-    leave the kernel. ``chunk`` is the number of episode rows each grid step
-    owns; the batch is row-padded (+inf times scan zero tiles: a no-op) up
-    to a chunk multiple.
+    ``B`` must be a multiple of ``rows`` (the greedy chunk: episode rows
+    folded together, on lanes). Returns ``(counts i32[B], end_out f32[B],
+    n_superset i32[B])``: final non-overlapped counts (carry-in
+    ``prev_count`` included), the carried-out greedy ``prev_end`` and the
+    tracked superset sizes.
     """
-    batch, n, cap = times_by_sym.shape
-    levels = n - 1
-    if levels < 1:
-        raise ValueError("need at least a 2-symbol episode for the kernel")
-    bn = min(block_next, cap)
-    bp = min(block_prev, cap)
-    if cap % bn or cap % bp:
-        raise ValueError(f"cap={cap} must be a multiple of block sizes {bn},{bp}")
-    next_tiles = cap // bn
-    r = max(1, min(chunk, batch))
-    nchunks = -(-batch // r)
-    pad = nchunks * r - batch
-    if pad:
-        def padrow(x, fill):
-            return jnp.concatenate(
-                [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
-        times_by_sym = padrow(times_by_sym, jnp.inf)
-        t_low = padrow(t_low, 0)
-        t_high = padrow(t_high, 0)
-        start_tile = padrow(start_tile, 0)
-        num_tiles = padrow(num_tiles, 0)
-        prev_end = padrow(prev_end, NEG)
-        prev_count = padrow(prev_count, 0)
-    kernel = pl.pallas_call(
-        functools.partial(
-            _count_batch_kernel, levels=levels, block_next=bn, block_prev=bp,
-            next_tiles=next_tiles),
-        grid=(nchunks,),
-        in_specs=[
-            pl.BlockSpec((r, n, cap), lambda c: (c, 0, 0)),
-            pl.BlockSpec((r, levels), lambda c: (c, 0)),
-            pl.BlockSpec((r, levels), lambda c: (c, 0)),
-            pl.BlockSpec((r, levels, next_tiles), lambda c: (c, 0, 0)),
-            pl.BlockSpec((r, levels, next_tiles), lambda c: (c, 0, 0)),
-            pl.BlockSpec((r, 1), lambda c: (c, 0)),
-            pl.BlockSpec((r, 1), lambda c: (c, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r, 1), lambda c: (c, 0)),
-            pl.BlockSpec((r, 1), lambda c: (c, 0)),
-            pl.BlockSpec((r, 1), lambda c: (c, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nchunks * r, 1), jnp.int32),
-            jax.ShapeDtypeStruct((nchunks * r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nchunks * r, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-    cnt, end, nsup = kernel(
-        times_by_sym, t_low.astype(jnp.float32), t_high.astype(jnp.float32),
-        start_tile, num_tiles,
-        prev_end.astype(jnp.float32)[:, None], prev_count[:, None])
-    return cnt[:batch, 0], end[:batch, 0], nsup[:batch, 0]
+    batch = times4.shape[0]
+    nchunks = batch // rows
+    cnt, end, nsup = _call(
+        times4, tab, win,
+        (prev_end.astype(jnp.float32).reshape(nchunks, 1, rows),
+         prev_count.astype(jnp.int32).reshape(nchunks, 1, rows)),
+        block_next=block_next, block_prev=block_prev, rows=rows, count=True,
+        seeded=False, interpret=interpret, vmem_limit=vmem_limit)
+    return cnt.reshape(batch), end.reshape(batch), nsup.reshape(batch)

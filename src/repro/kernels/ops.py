@@ -1,16 +1,20 @@
-"""jit'd public wrappers around the Pallas kernels (+ exactness bounds).
+"""jit'd public wrappers around the Pallas kernel (+ exactness bounds).
 
-Two entry points drive ``episode_track``:
+Three entry points drive ``episode_track``'s one kernel body:
 
-* :func:`track_level` — one tracking level, one ``pallas_call``. Arrays of
-  any capacity are accepted: they are padded up to a tile multiple (+inf
-  times / -inf values — a max-accumulation no-op) instead of degrading the
-  block sizes to a divisor of the capacity.
-* :func:`track_batch` — the fused batched path: a whole ``[B, N, cap]``
-  candidate batch's multi-level tracking in ONE launch, with the
-  per-(episode, level, next-tile) scan table precomputed here (the paper's
-  per-type index made block-level, batched) and window-cap truncation
-  *flagged, never silent*.
+* :func:`track_level` — one tracking level, seeded with given values.
+* :func:`track_batch` — a whole ``[B, N, cap]`` candidate batch's
+  multi-level tracking in ONE launch, with the per-(episode, level,
+  next-tile) scan table precomputed here (the paper's per-type index made
+  block-level, batched) and window-cap truncation *flagged, never silent*.
+* :func:`count_batch` — the same walk plus the in-kernel greedy count: the
+  single-launch count pipeline.
+
+Arrays of any capacity are accepted: they are padded up to a tile
+multiple (+inf times / -inf values — a max-accumulation no-op).
+:func:`tile_geometry` is the one tiling rule; compiled for the chip it
+refuses tiles Mosaic cannot lay out, and every launch on the chip is sized
+against :data:`VMEM_BUDGET_BYTES` before it reaches the compiler.
 
 The window-span math (`searchsorted` over next-tile extrema) is shared by
 the static host-side bounds (:func:`required_window_tiles`,
@@ -26,19 +30,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.episodes import sub_ceil
 from . import episode_track as _et
 from . import ref as _ref
 
 NEG = -jnp.inf
-
-# Interpret-mode (off-TPU) batching granularity for the fused kernel: the
-# interpret grid loop carries the full operand buffers through a
-# lax.while_loop and writes blocks back each step, which costs
-# O(grid_steps x batch_buffer) — quadratic in the batch size. Mapping over
-# fixed-size chunks keeps the emulation linear; on real TPUs the kernel is
-# launched once for the whole batch and this constant is irrelevant.
-_INTERPRET_BATCH_CHUNK = 8
-
 
 # ---------------------------------------------------------------------------
 # Window-span bounds (shared: host bounds + traced fused precompute)
@@ -81,7 +77,7 @@ def _tile_spans(
     tile_max = jnp.max(jnp.where(finite, tiles, -jnp.inf), axis=-1)
     has = finite[..., 0]
     t_high = jnp.asarray(t_high, jnp.float32)[..., None]
-    lo_idx = _searchsorted_rows(t_prev, tile_min - t_high)
+    lo_idx = _searchsorted_rows(t_prev, sub_ceil(tile_min, t_high))
     hi_idx = _searchsorted_rows(t_prev, tile_max)
     return lo_idx.astype(jnp.int32), hi_idx.astype(jnp.int32), has
 
@@ -184,23 +180,99 @@ def window_scan_table(
 
 
 # ---------------------------------------------------------------------------
-# Tile padding (replaces the old largest-divisor block-size degradation)
+# Tile geometry (one rule for every Pallas path)
 # ---------------------------------------------------------------------------
 
+#: Per-grid-step VMEM the kernel may plan for on the chip (v5e has 128 MiB
+#: of VMEM per core; the compiler's default scoped limit is 16 MiB, so
+#: launches above it raise ``vmem_limit_bytes`` explicitly).
+VMEM_BUDGET_BYTES = 48 * 2**20
+_VMEM_HEADROOM_BYTES = 8 * 2**20
+_DEFAULT_SCOPED_VMEM_BYTES = 16 * 2**20
 
-def tile_geometry(cap: int, block_next: int, block_prev: int) -> Tuple[int, int, int]:
-    """(bn, bp, padded_cap): the ONE tiling rule every Pallas tracking path
-    shares — blocks kept as requested, capacity rounded up to their lcm.
-    Padding with +inf times / -inf values is a max-accumulation no-op, so
-    tiling efficiency never degrades toward block size 1 for prime or odd
-    capacities. The truncation-flag parity between the ``dense_pallas`` and
-    ``dense_pallas_fused`` engines depends on this rule being
-    single-sourced (tracking._pallas_tile_geometry delegates here)."""
-    bn = max(1, block_next)
-    bp = max(1, block_prev)
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` = interpret mode anywhere but on a TPU backend."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def lanes(block_next: int, block_prev: int) -> int:
+    """Lane width ``W`` of the kernel's ``[K, W]`` row layout: the largest
+    common divisor of both tiles up to 128 (128 whenever the chip runs)."""
+    return math.gcd(block_next, block_prev, _et.LANES)
+
+
+def tile_geometry(cap: int, block_next: int, block_prev: int, *,
+                  interpret: Optional[bool] = None) -> Tuple[int, int, int]:
+    """(bn, bp, padded_cap): the ONE tiling rule every Pallas path shares —
+    blocks kept as requested, capacity rounded up to their lcm. Padding
+    with +inf times / -inf values is a max-accumulation no-op, so tiling
+    efficiency never degrades toward block size 1 for prime or odd
+    capacities. The truncation-flag parity between the ``dense_pallas``
+    and ``dense_pallas_fused`` engines depends on this rule being
+    single-sourced (tracking._pallas_tile_geometry delegates here).
+
+    Compiled for the chip (``interpret`` False), both tiles must be
+    multiples of the 128-lane vreg width; anything else raises here, before
+    Mosaic sees it. Interpret mode accepts any positive tile.
+    """
+    bn = int(block_next)
+    bp = int(block_prev)
+    if bn < 1 or bp < 1:
+        raise ValueError(f"tiles must be positive, got ({bn}, {bp})")
+    if not resolve_interpret(interpret) and (bn % _et.LANES or bp % _et.LANES):
+        raise ValueError(
+            f"block_next={bn} / block_prev={bp}: the TPU kernel needs tiles "
+            f"that are multiples of {_et.LANES} lanes")
     tile = math.lcm(bn, bp)
     pcap = ((cap + tile - 1) // tile) * tile
     return bn, bp, pcap
+
+
+def _vmem_limit(need: int, interpret: bool) -> Optional[int]:
+    if interpret:
+        return None
+    if need > VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"kernel needs ~{need / 2**20:.1f} MiB of VMEM per grid step "
+            f"(budget {VMEM_BUDGET_BYTES // 2**20} MiB): lower cap (the "
+            "per-type event capacity), the episode length or chunk")
+    return max(_DEFAULT_SCOPED_VMEM_BYTES, need + _VMEM_HEADROOM_BYTES)
+
+
+def count_rows(chunk: int, batch: int, levels: int, pcap: int, w: int, *,
+               interpret: bool) -> int:
+    """Episode rows per greedy chunk of the count kernel.
+
+    At most ``chunk`` and the (8-rounded, on the chip) batch; on the chip
+    halved until the chunk scratch fits :data:`VMEM_BUDGET_BYTES`.
+    """
+    rows = max(1, min(int(chunk), batch))
+    if interpret:
+        return rows
+    rows = -(-rows // 8) * 8
+    while rows > 8 and _et.vmem_bytes(levels, pcap, w, rows,
+                                      count=True) > VMEM_BUDGET_BYTES:
+        rows //= 2
+    return rows
+
+
+def kernel_vmem_bytes(kind: str, levels: int, cap: int, block_next: int,
+                      block_prev: int, chunk: int, batch: int) -> int:
+    """Chip VMEM estimate for one launch of ``kind`` (``"count"`` |
+    ``"track"``), as :func:`count_batch` / :func:`track_batch` would size
+    it — the number staticcheck REPRO104 holds against the budget."""
+    bn, bp, pcap = tile_geometry(cap, block_next, block_prev,
+                                 interpret=False)
+    w = lanes(bn, bp)
+    if kind == "count":
+        rows = count_rows(chunk, batch, levels, pcap, w, interpret=False)
+        return _et.vmem_bytes(levels, pcap, w, rows, count=True)
+    if kind == "track":
+        return _et.vmem_bytes(levels, pcap, w, 1, count=False)
+    raise ValueError(f"unknown kernel kind {kind!r}")
 
 
 def _pad_tail(x: jax.Array, pcap: int, fill) -> jax.Array:
@@ -209,6 +281,38 @@ def _pad_tail(x: jax.Array, pcap: int, fill) -> jax.Array:
         return x
     pad = jnp.full(x.shape[:-1] + (pcap - cap,), fill, x.dtype)
     return jnp.concatenate([x, pad], axis=-1)
+
+
+def _pad_rows(x: jax.Array, rows: int, fill) -> jax.Array:
+    b = x.shape[0]
+    if rows == b:
+        return x
+    return jnp.concatenate(
+        [x, jnp.full((rows - b,) + x.shape[1:], fill, x.dtype)])
+
+
+def _scan_operands(padded, t_low, t_high, bn, bp, window_tiles):
+    """Kernel operands for ``[B, N, pcap]`` padded rows: the ``[B, N, K, W]``
+    row layout, the per-row i32 scan table (layout in
+    ``episode_track._rows_kernel``), the f32 window table, and the per-row
+    truncation flag."""
+    batch, n, pcap = padded.shape
+    w = lanes(bn, bp)
+    start, num, truncated = window_scan_table(
+        padded, t_high, bn, bp, window_tiles)
+    nt = start.shape[-1]
+    # scan next tiles up to the last one with a non-empty window; the rest
+    # keep the kernel's -inf prefill
+    last = jnp.max(jnp.where(num > 0, jnp.arange(1, nt + 1), 0), axis=-1)
+    n_end = jnp.sum(jnp.isfinite(padded[:, -1, :]), axis=-1)
+    tab = jnp.concatenate(
+        [start.reshape(batch, -1), num.reshape(batch, -1),
+         last.astype(jnp.int32), n_end.astype(jnp.int32)[:, None]], axis=-1)
+    win = jnp.concatenate(
+        [jnp.asarray(t_low, jnp.float32).reshape(batch, -1),
+         jnp.asarray(t_high, jnp.float32).reshape(batch, -1)], axis=-1)
+    return (padded.reshape(batch, n, pcap // w, w), tab[:, None, :],
+            win[:, None, :], truncated)
 
 
 # ---------------------------------------------------------------------------
@@ -223,25 +327,36 @@ def track_level(
     t_low: float,
     t_high: float,
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
+    block_next: int = 128,
+    block_prev: int = 128,
     window_tiles: int = 0,
     use_kernel: bool = True,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """One tracking level; Pallas kernel on TPU, oracle fallback elsewhere."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """One tracking level; the batched kernel on a one-row, one-level batch
+    seeded with ``v_prev``. ``use_kernel=False`` runs the jnp oracle."""
     if not use_kernel:
         return _ref.track_level_ref(t_prev, v_prev, t_next, t_low, t_high)
+    interpret = resolve_interpret(interpret)
     cap = t_prev.shape[0]
-    bn, bp, pcap = tile_geometry(cap, block_next, block_prev)
-    out = _et.track_level_pallas(
-        _pad_tail(t_prev, pcap, jnp.inf), _pad_tail(v_prev, pcap, NEG),
-        _pad_tail(t_next, pcap, jnp.inf), t_low, t_high,
-        block_next=bn, block_prev=bp, window_tiles=window_tiles,
-        interpret=interpret)
-    return out[:cap]
+    bn, bp, pcap = tile_geometry(cap, block_next, block_prev,
+                                 interpret=interpret)
+    w = lanes(bn, bp)
+    rows = jnp.stack([_pad_tail(jnp.asarray(t_prev, jnp.float32), pcap,
+                                jnp.inf),
+                      _pad_tail(jnp.asarray(t_next, jnp.float32), pcap,
+                                jnp.inf)])[None]
+    times4, tab, win, _ = _scan_operands(
+        rows, jnp.full((1, 1), t_low, jnp.float32),
+        jnp.full((1, 1), t_high, jnp.float32), bn, bp, window_tiles)
+    seed = _pad_tail(jnp.asarray(v_prev, jnp.float32), pcap, NEG)
+    out, _ = _et.track_batch_pallas(
+        times4, tab, win, seed.reshape(1, pcap // w, w),
+        block_next=bn, block_prev=bp, interpret=interpret,
+        vmem_limit=_vmem_limit(
+            _et.vmem_bytes(1, pcap, w, 1, count=False, seeded=True),
+            interpret))
+    return out.reshape(pcap)[:cap]
 
 
 def track_episode(
@@ -249,8 +364,8 @@ def track_episode(
     t_low,
     t_high,
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
+    block_next: int = 128,
+    block_prev: int = 128,
     window_tiles: int = 0,
     interpret: Optional[bool] = None,
 ):
@@ -277,13 +392,26 @@ def track_episode(
 # ---------------------------------------------------------------------------
 
 
+def _fold_lead(fn, times_by_sym, t_low, t_high, *carries, **kw):
+    """Fold stacked leading dims (a ``[S, B, ...]`` corpus) into the kernel's
+    one batch axis — THE one fold; per-row scan tables are row-independent,
+    so the flattened layout is fold-invariant — and unfold the outputs."""
+    lead = times_by_sym.shape[:-2]
+    rows = math.prod(lead)
+    outs = fn(times_by_sym.reshape((rows,) + times_by_sym.shape[-2:]),
+              t_low.reshape((rows,) + t_low.shape[-1:]),
+              t_high.reshape((rows,) + t_high.shape[-1:]),
+              *(jnp.reshape(c, rows) for c in carries), **kw)
+    return tuple(o.reshape(lead + o.shape[1:]) for o in outs)
+
+
 def track_batch(
     times_by_sym: jax.Array,    # f32[..., N, cap] sorted rows, +inf padded
     t_low: jax.Array,           # f32[..., N-1]
     t_high: jax.Array,          # f32[..., N-1]
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
+    block_next: int = 128,
+    block_prev: int = 128,
     window_tiles: int = 0,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -297,59 +425,30 @@ def track_batch(
     latency bound — possible truncation is flagged, never silent.
 
     Stream axis: stacked leading dims — a ``[S, B, N, cap]`` corpus of
-    ``S`` streams by ``B`` episodes — fold into the kernel's batch grid
-    dimension here (THE one fold; per-row scan tables are row-independent,
-    so the flattened layout is fold-invariant) and unfold on the way out.
-    One corpus, one launch.
+    ``S`` streams by ``B`` episodes — fold into the kernel's batch axis.
     """
-    lead = times_by_sym.shape[:-2]
-    if len(lead) > 1:
-        rows = math.prod(lead)
-        starts, nsup, truncated = track_batch(
-            times_by_sym.reshape((rows,) + times_by_sym.shape[-2:]),
-            t_low.reshape((rows,) + t_low.shape[-1:]),
-            t_high.reshape((rows,) + t_high.shape[-1:]),
-            block_next=block_next, block_prev=block_prev,
-            window_tiles=window_tiles, interpret=interpret)
-        return (starts.reshape(lead + starts.shape[-1:]),
-                nsup.reshape(lead), truncated.reshape(lead))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    kw = dict(block_next=block_next, block_prev=block_prev,
+              window_tiles=window_tiles, interpret=interpret)
+    if times_by_sym.ndim > 3:
+        return _fold_lead(track_batch, times_by_sym, t_low, t_high, **kw)
+    interpret = resolve_interpret(interpret)
     batch, n, cap = times_by_sym.shape
     t0 = times_by_sym[:, 0, :]
     if n == 1:  # no transitions: every first-symbol event is an occurrence
         starts = jnp.where(jnp.isfinite(t0), t0, NEG)
         nsup = jnp.sum(jnp.isfinite(t0), axis=-1).astype(jnp.int32)
         return starts, nsup, jnp.zeros((batch,), bool)
-    bn, bp, pcap = tile_geometry(cap, block_next, block_prev)
-    padded = _pad_tail(times_by_sym, pcap, jnp.inf)
-    start_tile, num_tiles, truncated = window_scan_table(
-        padded, t_high, bn, bp, window_tiles)
-    t_low = jnp.asarray(t_low, jnp.float32)
-    t_high = jnp.asarray(t_high, jnp.float32)
-    chunk = _INTERPRET_BATCH_CHUNK
-    if interpret and batch > chunk:
-        nchunks = -(-batch // chunk)
-        pad_rows = nchunks * chunk - batch
-
-        def chunked(x, fill):
-            if pad_rows:   # all-padding rows scan zero tiles: a no-op
-                x = jnp.concatenate(
-                    [x, jnp.full((pad_rows,) + x.shape[1:], fill, x.dtype)])
-            return x.reshape((nchunks, chunk) + x.shape[1:])
-
-        starts, nsup = jax.lax.map(
-            lambda xs: _et.track_batch_pallas(
-                *xs, block_next=bn, block_prev=bp, interpret=True),
-            (chunked(padded, jnp.inf), chunked(t_low, 0), chunked(t_high, 0),
-             chunked(start_tile, 0), chunked(num_tiles, 0)))
-        starts = starts.reshape(nchunks * chunk, pcap)[:batch]
-        nsup = nsup.reshape(-1)[:batch]
-    else:
-        starts, nsup = _et.track_batch_pallas(
-            padded, t_low, t_high, start_tile, num_tiles,
-            block_next=bn, block_prev=bp, interpret=interpret)
-    return starts[:, :cap], nsup, truncated
+    bn, bp, pcap = tile_geometry(cap, block_next, block_prev,
+                                 interpret=interpret)
+    times4, tab, win, truncated = _scan_operands(
+        _pad_tail(times_by_sym, pcap, jnp.inf), t_low, t_high, bn, bp,
+        window_tiles)
+    starts, nsup = _et.track_batch_pallas(
+        times4, tab, win, block_next=bn, block_prev=bp, interpret=interpret,
+        vmem_limit=_vmem_limit(
+            _et.vmem_bytes(n - 1, pcap, lanes(bn, bp), 1, count=False),
+            interpret))
+    return starts.reshape(batch, pcap)[:, :cap], nsup, truncated
 
 
 def track_corpus(
@@ -357,17 +456,17 @@ def track_corpus(
     t_low: jax.Array,           # f32[B, N-1] shared across streams
     t_high: jax.Array,          # f32[B, N-1]
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
+    block_next: int = 128,
+    block_prev: int = 128,
     window_tiles: int = 0,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """A whole corpus of streams x candidate batch in one fused launch.
 
-    The stream axis folds into the kernel's batch grid dimension —
-    ``(stream, episode)`` rows are independent, and each folded row's scan
-    offsets come from its own stream's per-type index — so ragged stream
-    lengths cost +inf padding inside ``cap``, never extra launches.
+    The stream axis folds into the kernel's batch axis — ``(stream,
+    episode)`` rows are independent, and each folded row's scan offsets
+    come from its own stream's per-type index — so ragged stream lengths
+    cost +inf padding inside ``cap``, never extra launches.
 
     Returns ``(starts f32[S, B, cap], n_superset i32[S, B],
     truncated bool[S, B])``; the per-episode windows are broadcast over the
@@ -397,40 +496,31 @@ def count_batch(
     prev_end: jax.Array,        # f32[...] carried greedy prev_end
     prev_count: jax.Array,      # i32[...] carried greedy count
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
+    block_next: int = 128,
+    block_prev: int = 128,
     window_tiles: int = 0,
-    chunk: int = 8,
+    chunk: int = 32,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Whole candidate batch, tracking + compaction + greedy, one launch.
+    """Whole candidate batch, tracking + greedy, one launch.
 
     Returns ``(counts i32[B], end_out f32[B], n_superset i32[B],
     truncated bool[B])``. Counts include the ``prev_count`` carry-in and
     ``end_out`` is the carried greedy state, so streaming chain-state
     stitching works exactly as with the track + host-greedy path. The
     per-(episode, level) latest-start tables and occurrence intervals never
-    leave VMEM — only these O(B) scalars do. ``chunk`` sets how many episode
-    rows each grid step owns (the count-kernel analogue of the track path's
-    interpret chunking; on TPU it bounds per-step VMEM).
+    leave VMEM — only these O(B) scalars do. ``chunk`` bounds how many
+    episode rows share one vectorized greedy fold (:func:`count_rows`).
 
-    Stream axis: stacked leading dims fold into the kernel's batch grid
-    dimension, mirroring :func:`track_batch`.
+    Stream axis: stacked leading dims fold into the kernel's batch axis,
+    mirroring :func:`track_batch`.
     """
-    lead = times_by_sym.shape[:-2]
-    if len(lead) > 1:
-        rows = math.prod(lead)
-        counts, end_out, nsup, truncated = count_batch(
-            times_by_sym.reshape((rows,) + times_by_sym.shape[-2:]),
-            t_low.reshape((rows,) + t_low.shape[-1:]),
-            t_high.reshape((rows,) + t_high.shape[-1:]),
-            prev_end.reshape(rows), prev_count.reshape(rows),
-            block_next=block_next, block_prev=block_prev,
-            window_tiles=window_tiles, chunk=chunk, interpret=interpret)
-        return (counts.reshape(lead), end_out.reshape(lead),
-                nsup.reshape(lead), truncated.reshape(lead))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    kw = dict(block_next=block_next, block_prev=block_prev,
+              window_tiles=window_tiles, chunk=chunk, interpret=interpret)
+    if times_by_sym.ndim > 3:
+        return _fold_lead(count_batch, times_by_sym, t_low, t_high,
+                          prev_end, prev_count, **kw)
+    interpret = resolve_interpret(interpret)
     batch, n, cap = times_by_sym.shape
     prev_end = jnp.asarray(prev_end, jnp.float32)
     prev_count = jnp.asarray(prev_count, jnp.int32)
@@ -449,16 +539,24 @@ def count_batch(
         end_out = jnp.where(cnt > 0, last, prev_end)
         nsup = jnp.sum(finite, axis=-1).astype(jnp.int32)
         return prev_count + cnt, end_out, nsup, jnp.zeros((batch,), bool)
-    bn, bp, pcap = tile_geometry(cap, block_next, block_prev)
-    padded = _pad_tail(times_by_sym, pcap, jnp.inf)
-    start_tile, num_tiles, truncated = window_scan_table(
-        padded, t_high, bn, bp, window_tiles)
+    bn, bp, pcap = tile_geometry(cap, block_next, block_prev,
+                                 interpret=interpret)
+    w = lanes(bn, bp)
+    rows = count_rows(chunk, batch, n - 1, pcap, w, interpret=interpret)
+    total = -(-batch // rows) * rows     # +inf rows scan zero tiles: no-ops
+    times4, tab, win, truncated = _scan_operands(
+        _pad_rows(_pad_tail(times_by_sym, pcap, jnp.inf), total, jnp.inf),
+        _pad_rows(jnp.asarray(t_low, jnp.float32), total, 0),
+        _pad_rows(jnp.asarray(t_high, jnp.float32), total, 0), bn, bp,
+        window_tiles)
     counts, end_out, nsup = _et.count_batch_pallas(
-        padded, jnp.asarray(t_low, jnp.float32),
-        jnp.asarray(t_high, jnp.float32), start_tile, num_tiles,
-        prev_end, prev_count,
-        block_next=bn, block_prev=bp, chunk=chunk, interpret=interpret)
-    return counts, end_out, nsup, truncated
+        times4, tab, win, _pad_rows(prev_end, total, NEG),
+        _pad_rows(prev_count, total, 0),
+        block_next=bn, block_prev=bp, rows=rows, interpret=interpret,
+        vmem_limit=_vmem_limit(
+            _et.vmem_bytes(n - 1, pcap, w, rows, count=True), interpret))
+    return (counts[:batch], end_out[:batch], nsup[:batch],
+            truncated[:batch])
 
 
 def count_corpus(
@@ -466,10 +564,10 @@ def count_corpus(
     t_low: jax.Array,           # f32[B, N-1] shared across streams
     t_high: jax.Array,          # f32[B, N-1]
     *,
-    block_next: int = 256,
-    block_prev: int = 256,
+    block_next: int = 128,
+    block_prev: int = 128,
     window_tiles: int = 0,
-    chunk: int = 8,
+    chunk: int = 32,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Corpus count: streams x episodes folded into one fused count launch.

@@ -4,17 +4,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..core.episodes import in_window
+
 NEG = -jnp.inf
 
 
 def track_level_ref(t_prev, v_prev, t_next, t_low, t_high) -> jax.Array:
     """Quadratic masked max — independent oracle for episode_track.
 
-    v_next[a] = max over {b : t_next[a]-hi <= t_prev[b] < t_next[a]-lo}
-    of v_prev[b]; -inf when empty.
+    v_next[a] = max over {b : t_low < t_next[a] - t_prev[b] <= t_high}
+    (exact, ``core.episodes.in_window``) of v_prev[b]; -inf when empty.
     """
-    ok = (t_prev[None, :] >= t_next[:, None] - t_high) & (
-        t_prev[None, :] < t_next[:, None] - t_low)
+    ok = in_window(t_next[:, None], t_prev[None, :], t_low, t_high)
     return jnp.max(jnp.where(ok, v_prev[None, :], NEG), axis=1)
 
 

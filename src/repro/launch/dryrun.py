@@ -107,10 +107,7 @@ def lower_cell(cfg, shape, mesh, *, remat: str = "full",
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    raw_cost = compiled.cost_analysis()
-    if isinstance(raw_cost, (list, tuple)):   # older jax: list of dicts
-        raw_cost = raw_cost[0]
-    raw_cost = dict(raw_cost)
+    raw_cost = dict(compiled.cost_analysis())
     ma = compiled.memory_analysis()
     memstats = _memstats_dict(ma)
     # trip-count-corrected per-device costs from the optimized HLO
@@ -124,7 +121,7 @@ def lower_cell(cfg, shape, mesh, *, remat: str = "full",
         arch=cfg.name, shape=shape.name, mesh_name=mesh_name, chips=chips,
         cost=cost,
         coll=coll, model_flops=rl.model_flops_for(cfg, shape, n_active_params(cfg)),
-        memstats=memstats)
+        device_kind=rl.V5E, memstats=memstats)
     op_mix = dict(sorted(parsed["op_mix"].items(),
                          key=lambda kv: -kv[1])[:24])
     report = {

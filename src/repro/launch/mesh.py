@@ -6,12 +6,7 @@ import jax
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; older versions default to
-    Auto semantics, so omitting it is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,5 +1,6 @@
 """HLO cost parser: trip counting, collective bytes, roofline math."""
 import jax
+import pytest
 import jax.numpy as jnp
 from jax import lax
 
@@ -12,9 +13,7 @@ def _compile(f, *shapes):
 
 
 def _cost_analysis(c):
-    ca = c.cost_analysis()
-    # older jax returns a one-element list of dicts, newer a dict
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
+    return c.cost_analysis()
 
 
 def test_flops_match_cost_analysis_no_while():
@@ -64,15 +63,24 @@ def test_roofline_terms_and_bottleneck():
     r = rl.analyze(
         arch="x", shape="train_4k", mesh_name="16x16", chips=256,
         cost={"flops": 1.97e14, "bytes accessed": 8.19e11},
-        coll={"total": 5e10}, model_flops=1.97e14 * 256 * 0.5)
+        coll={"total": 5e10}, model_flops=1.97e14 * 256 * 0.5,
+        device_kind=rl.V5E)
     assert abs(r.t_compute - 1.0) < 1e-6
     assert abs(r.t_memory - 1.0) < 1e-6
     assert abs(r.t_collective - 1.0) < 1e-6
     assert r.useful_ratio == 0.5
     r2 = rl.analyze(arch="x", shape="s", mesh_name="m", chips=1,
                     cost={"flops": 1.0, "bytes accessed": 1e15},
-                    coll={"total": 0.0}, model_flops=1.0)
+                    coll={"total": 0.0}, model_flops=1.0,
+                    device_kind=rl.V5E)
     assert r2.bottleneck == "memory"
+
+
+def test_roofline_unknown_device_raises():
+    with pytest.raises(KeyError, match="no published peaks"):
+        rl.analyze(arch="x", shape="s", mesh_name="m", chips=1,
+                   cost={"flops": 1.0}, coll={"total": 0.0}, model_flops=1.0,
+                   device_kind="cpu")
 
 
 def test_collective_bytes_from_sharded_module():

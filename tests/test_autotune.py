@@ -52,7 +52,7 @@ def test_resolve_missing_table_falls_back_to_defaults(tmp_path):
     cfg = autotune.resolve("count", 3, 128, 8,
                            path=str(tmp_path / "absent.json"))
     assert cfg == DEFAULTS["count"]
-    assert cfg == TileConfig(256, 256, 0, 8)
+    assert cfg == TileConfig(128, 128, 0, 32)
 
 
 def test_resolve_malformed_table_falls_back_to_defaults(tmp_path):
@@ -80,6 +80,20 @@ def test_resolve_uses_tuned_entry_and_fills_missing_fields(tmp_path):
     # fields absent from the entry come from DEFAULTS
     assert cfg.window_tiles == DEFAULTS["count"].window_tiles
     assert cfg.chunk == DEFAULTS["count"].chunk
+
+
+def test_resolve_serves_a_table_only_on_its_backend(tmp_path):
+    """CPU interpret-mode winners never reach the chip."""
+    p = tmp_path / "t.json"
+    key = autotune.bucket_key("count", 3, 1024, 8)
+    p.write_text(json.dumps({"backend": "cpu", "configs": {key: {
+        "block_next": 8, "block_prev": 16}}}))
+    on_cpu = autotune.resolve("count", 3, 1024, 8, path=str(p),
+                              backend="cpu")
+    assert (on_cpu.block_next, on_cpu.block_prev) == (8, 16)
+    on_tpu = autotune.resolve("count", 3, 1024, 8, path=str(p),
+                              backend="tpu")
+    assert on_tpu == DEFAULTS["count"]
 
 
 def test_resolve_explicit_overrides_beat_tuned_entry(tmp_path):

@@ -118,3 +118,65 @@ def test_boundary_grid_differential(seed):
             for parallel in SCHEDULERS:
                 got = _count(stream, ep, engine, parallel)
                 assert got == expected, (seed, str(ep), engine, parallel)
+
+
+# ---------------------------------------------------------------------------
+# Window edges off the f32 grid: exact, not rounded
+# ---------------------------------------------------------------------------
+
+
+def _straddling_gap(edge):
+    """(s, t, within): f32 times near 100 s whose exact gap ``t - s`` is on
+    one side of ``edge`` (``within``: ``t - s <= edge``) while the
+    f32-rounded threshold ``t - edge`` lands on the other side of ``s`` —
+    a pair a rounded window test misclassifies."""
+    s = np.float32(100.0)
+    edge32 = np.float32(edge)
+    t = np.float32(s + edge32)
+    for _ in range(64):
+        within = float(t) - float(s) <= float(edge32)
+        if within != bool(s >= np.float32(t - edge32)):
+            return s, t, within
+        t = np.nextafter(t, np.float32(np.inf), dtype=np.float32)
+    raise AssertionError("no straddling pair found")
+
+
+@pytest.mark.parametrize("side", ["hi", "lo"])
+def test_window_edges_exact_off_the_f32_grid(side):
+    s, t, within = _straddling_gap(0.01)
+    stream = EventStream(np.array([0, 1], np.int32),
+                         np.array([s, t], np.float32), 2)
+    if side == "hi":      # window (0, 0.01]: inside iff within
+        ep, expected = serial([0, 1], 0.0, 0.01), int(within)
+    else:                 # window (0.01, 1]: inside iff not within
+        ep, expected = serial([0, 1], 0.01, 1.0), int(not within)
+    _check_all(stream, ep, expected)
+
+
+def test_float64_window_bound_just_below_a_gap():
+    """hi = 1 - 2**-53 rounds to 1.0 as float32 to nearest; the gap 1.0 is
+    outside the given window, so every engine must count 0 like the FSM."""
+    stream = EventStream(np.array([1, 0], np.int32),
+                         np.array([0.0, 1.0], np.float32), 2)
+    _check_all(stream, serial([1, 0], 0.0, float(np.nextafter(1.0, 0.0))), 0)
+
+
+def test_sub_ceil_add_floor_match_float64():
+    from repro.core.episodes import add_floor, sub_ceil
+    rng = np.random.default_rng(0)
+    a = np.concatenate([rng.uniform(-300, 300, 4000),
+                        rng.uniform(0, 0.03, 1000)]).astype(np.float32)
+    c = np.concatenate([rng.uniform(0, 5, 4000),
+                        np.full(1000, 0.01)]).astype(np.float32)
+    got_ceil = np.asarray(sub_ceil(a, c))
+    got_floor = np.asarray(add_floor(a, c))
+    exact_sub = a.astype(np.float64) - c.astype(np.float64)
+    exact_add = a.astype(np.float64) + c.astype(np.float64)
+    # least f32 >= exact / greatest f32 <= exact
+    assert np.all(got_ceil.astype(np.float64) >= exact_sub)
+    below = np.nextafter(got_ceil, np.float32(-np.inf), dtype=np.float32)
+    assert np.all(below.astype(np.float64) < exact_sub)
+    assert np.all(got_floor.astype(np.float64) <= exact_add)
+    above = np.nextafter(got_floor, np.float32(np.inf), dtype=np.float32)
+    assert np.all(above.astype(np.float64) > exact_add)
+    assert np.isinf(np.asarray(sub_ceil(np.float32(np.inf), 0.5)))

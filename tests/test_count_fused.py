@@ -17,6 +17,7 @@ from repro.core.counting import (
     count_batch_indexed,
     count_batch_indexed_stateful,
 )
+from repro.core.episodes import episode_batch
 from repro.core.events import EventStream, type_index
 
 CAP = 128   # fixed capacity so seeded examples share compilations
@@ -54,10 +55,9 @@ def _random_case(seed, n_types=4, batch=4):
 def _indexed_batch(stream, episodes, cap=CAP):
     table, counts = type_index(
         stream.types, stream.times, stream.n_types, cap)
-    n = len(episodes[0].symbols)
-    sym = jnp.asarray([e.symbols for e in episodes], jnp.int32)
-    lo = jnp.asarray([e.t_low for e in episodes], jnp.float32).reshape(-1, n - 1)
-    hi = jnp.asarray([e.t_high for e in episodes], jnp.float32).reshape(-1, n - 1)
+    # the library's float32 windows (episode_batch), which decide every
+    # float32 gap exactly as the oracle's float64 bounds do
+    sym, lo, hi = episode_batch(episodes)
     return table, counts, sym, lo, hi
 
 

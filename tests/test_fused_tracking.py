@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.core import count_fsm_numpy, count_nonoverlapped, serial
 from repro.core.counting import count_batch_indexed
+from repro.core.episodes import episode_batch
 from repro.core.events import EventStream, type_index
 from repro.kernels import ops, ref
 
@@ -139,10 +140,9 @@ def test_required_window_tiles_batch_covers_each_level():
 def _indexed_batch(stream, episodes):
     table, counts = type_index(
         stream.types, stream.times, stream.n_types, CAP)
-    n = len(episodes[0].symbols)
-    sym = jnp.asarray([e.symbols for e in episodes], jnp.int32)
-    lo = jnp.asarray([e.t_low for e in episodes], jnp.float32).reshape(-1, n - 1)
-    hi = jnp.asarray([e.t_high for e in episodes], jnp.float32).reshape(-1, n - 1)
+    # the library's float32 windows (episode_batch), which decide every
+    # float32 gap exactly as the oracle's float64 bounds do
+    sym, lo, hi = episode_batch(episodes)
     return table, counts, sym, lo, hi
 
 

@@ -238,6 +238,20 @@ def test_oversized_plan_falls_back_with_warning(monkeypatch):
     assert stats["size"] == 0               # nothing cached
 
 
+def test_cached_executable_rejects_mismatched_inputs():
+    """Inputs that miss the plan's specs are an adapter bug: the cached
+    executable's error propagates instead of a silent uncached recompile."""
+    table, counts, sym, lo, hi = _indexed_case(60, 8)
+    counting.count_batch_indexed(table, counts, sym, lo, hi)
+    p = plan.plan_for("count_indexed", level=2, n_types=4, cap=60, batch=8)
+    assert p in plan.cached_plans()
+    wrong = jnp.zeros((4, 2 * p.cap), jnp.float32)   # table twice too wide
+    with pytest.raises((TypeError, ValueError)):
+        plan.dispatch(p, wrong, counts, jnp.int32(60), jnp.asarray(sym),
+                      jnp.asarray(lo), jnp.asarray(hi))
+    assert plan.cache_stats()["fallbacks"] == 0
+
+
 def test_malformed_plan_is_uncacheable_not_fatal():
     bad = plan.MiningPlan(fn="count_indexed", level=1, n_types=4,
                           cap=64, batch=8)

@@ -64,17 +64,29 @@ def test_count_bounded_by_min_symbol_count(s, ep):
 
 
 @settings(max_examples=25, deadline=None)
-@given(sts.streams(), sts.episodes(), st.floats(0.1, 10.0))
+@given(sts.streams(), sts.episodes(),
+       st.one_of(st.floats(0.1, 10.0),
+                 st.sampled_from([0.125, 0.25, 0.5, 2.0, 4.0, 8.0])))
 def test_time_scale_invariance(s, ep, scale):
-    """Scaling all times and windows by the same factor preserves counts."""
+    """Scaling all times and windows by the same factor preserves counts
+    exactly when the scaling is exact in float32 (a power of two). Any
+    other factor rounds the scaled times and windows, which moves pairs
+    across window edges — duplicate timestamps move together, so no fixed
+    tolerance holds even for the FSM oracle; there the scaled count must
+    equal the oracle's count of the scaled stream (windows given as the
+    float32 values the engines hold)."""
     ep = serial([x % s.n_types for x in ep.symbols], 0.25, 2.25)
     base = int(count_nonoverlapped(s, ep, engine="dense").count)
     s2 = EventStream(s.types, (np.asarray(s.times) * scale).astype(np.float32),
                      s.n_types)
-    ep2 = serial(list(ep.symbols), 0.25 * scale, 2.25 * scale)
+    ep2 = serial(list(ep.symbols), float(np.float32(0.25 * scale)),
+                 float(np.float32(2.25 * scale)))
     got = int(count_nonoverlapped(s2, ep2, engine="dense").count)
-    # float32 rounding at window boundaries can flip an inclusion; allow 1
-    assert abs(got - base) <= 1
+    if float(np.log2(scale)).is_integer():
+        assert got == base
+    else:
+        assert got == count_fsm_numpy(np.asarray(s2.types),
+                                      np.asarray(s2.times), ep2)
 
 
 @settings(max_examples=25, deadline=None)

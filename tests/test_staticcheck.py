@@ -432,8 +432,10 @@ class TestJaxprLayer:
         assert jaxpr_checks.check_tmin(p, 2) != []
 
     def test_tile_contract_flags_overbudget_vmem(self):
+        # a chip launch whose per-row times block alone (4 rows of 4M
+        # events, double-buffered) is far past the VMEM budget
         fs = jaxpr_checks._tile_contract(
-            "plan://synthetic", "count", 3, 1 << 16, 64, 256, 256, 0, 64)
+            "plan://synthetic", "count", 3, 1 << 22, 64, 256, 256, 0, 64)
         assert "REPRO104" in codes(fs)
 
     def test_tuned_table_clean(self):
