@@ -251,8 +251,10 @@ def _build_count_indexed(p: plan_mod.MiningPlan):
     def fn(table, counts, build_cap, symbols, t_low, t_high):
         plan_mod.note_trace(p)
         index_overflow = jnp.any(counts > build_cap)
+        with jax.named_scope("table_gather"):
+            rows = table[symbols]
         batch_counts, _, n_superset, overflow = count_batch_dispatch(
-            tracking.get_engine(p.engine), table[symbols], t_low, t_high,
+            tracking.get_engine(p.engine), rows, t_low, t_high,
             *_fresh_carries(symbols.shape[0]), _engine_cfg(p),
             parallel_schedule=p.parallel_schedule)
         return batch_counts, n_superset, overflow | index_overflow
@@ -264,8 +266,10 @@ def _build_count_stateful(p: plan_mod.MiningPlan):
            prev_end, prev_count):
         plan_mod.note_trace(p)
         index_overflow = jnp.any(counts > build_cap)
+        with jax.named_scope("table_gather"):
+            rows = table[symbols]
         count_out, end_out, n_superset, overflow = count_batch_dispatch(
-            tracking.get_engine(p.engine), table[symbols], t_low, t_high,
+            tracking.get_engine(p.engine), rows, t_low, t_high,
             prev_end, prev_count, _engine_cfg(p),
             parallel_schedule=p.parallel_schedule)
         return count_out, end_out, n_superset, overflow | index_overflow
@@ -292,8 +296,9 @@ def _build_count_tail(p: plan_mod.MiningPlan):
         needed = jnp.minimum(counts, build_cap)[symbols] - starts
         tail_short = jnp.any(needed > tail_cap, axis=-1)   # [B]
         idx = starts[:, :, None] + jnp.arange(tail_cap, dtype=jnp.int32)
-        view = table[symbols[:, :, None], jnp.minimum(idx, cap - 1)]
-        view = jnp.where(idx < cap, view, jnp.inf)         # [B, N, tail_cap]
+        with jax.named_scope("table_gather"):
+            view = table[symbols[:, :, None], jnp.minimum(idx, cap - 1)]
+            view = jnp.where(idx < cap, view, jnp.inf)     # [B, N, tail_cap]
 
         index_overflow = jnp.any(counts > build_cap)
         count_out, end_out, n_superset, overflow = count_batch_dispatch(
@@ -312,11 +317,13 @@ def _build_count_corpus(p: plan_mod.MiningPlan):
         index_overflow = jnp.any(counts > build_cap, axis=-1)   # [S]
         eng = tracking.get_engine(p.engine)
         cfg = _engine_cfg(p)
+        with jax.named_scope("table_gather"):
+            rows = tables[:, symbols]
         if getattr(eng, "count_batch", None) is not None:
             # corpus-native counting: (stream, episode) rows fold into ONE
             # single-launch count pipeline call — fresh carries, stateless
             corpus_counts, _, n_superset, overflow = count_batch_dispatch(
-                eng, tables[:, symbols],
+                eng, rows,
                 jnp.broadcast_to(t_low[None], (s,) + t_low.shape),
                 jnp.broadcast_to(t_high[None], (s,) + t_high.shape),
                 jnp.full((s, b), -jnp.inf, jnp.float32),
@@ -324,7 +331,7 @@ def _build_count_corpus(p: plan_mod.MiningPlan):
                 parallel_schedule=p.parallel_schedule)
         else:
             occ = tracking.track_corpus_dispatch(
-                eng, tables[:, symbols], t_low, t_high, cfg)
+                eng, rows, t_low, t_high, cfg)
 
             def schedule(starts, ends, valid):
                 one = tracking.Occurrences(
@@ -362,9 +369,10 @@ def _build_count_corpus_tail(p: plan_mod.MiningPlan):
         tail_short = jnp.any(needed > tail_cap, axis=-1)   # [S, B]
         idx = starts[..., None] + jnp.arange(tail_cap, dtype=jnp.int32)
         stream_ix = jnp.arange(s, dtype=jnp.int32)[:, None, None, None]
-        view = tables[stream_ix, symbols[None, :, :, None],
-                      jnp.minimum(idx, cap - 1)]
-        view = jnp.where(idx < cap, view, jnp.inf)     # [S, B, N, tail_cap]
+        with jax.named_scope("table_gather"):
+            view = tables[stream_ix, symbols[None, :, :, None],
+                          jnp.minimum(idx, cap - 1)]
+            view = jnp.where(idx < cap, view, jnp.inf)  # [S, B, N, tail_cap]
         # no t_min here: each session's seed row already starts at its own
         # suffix_start, so the scalar seed restriction count_tail threads
         # through EngineConfig is a provable no-op on this view (the shift
@@ -407,9 +415,10 @@ def _build_count_corpus_tail_grouped(p: plan_mod.MiningPlan):
         tail_short = jnp.any(needed > tail_cap, axis=-1)   # [S, B]
         idx = starts[..., None] + jnp.arange(tail_cap, dtype=jnp.int32)
         stream_ix = jnp.arange(s, dtype=jnp.int32)[:, None, None, None]
-        view = tables[stream_ix, symbols[..., None],
-                      jnp.minimum(idx, cap - 1)]
-        view = jnp.where(idx < cap, view, jnp.inf)     # [S, B, N, tail_cap]
+        with jax.named_scope("table_gather"):
+            view = tables[stream_ix, symbols[..., None],
+                          jnp.minimum(idx, cap - 1)]
+            view = jnp.where(idx < cap, view, jnp.inf)  # [S, B, N, tail_cap]
         # same no-t_min argument as count_corpus_tail: each row's seed view
         # already begins at its own suffix_start, so seed restriction is a
         # provable no-op

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from . import counting, distributed
+from . import counting, distributed, obs
 from . import events as events_lib
 from . import plan as plan_mod
 from .episodes import (Episode, episode_batch, episodes_from_rows,
@@ -259,25 +259,35 @@ def _mine_levels(cfg: MinerConfig, level1: LevelArrays,
     flags_dev[B])`` pairs raised on when any flag is set. Each level pays
     exactly ONE host sync: counts, keep mask, and every check flag come
     back in a single ``device_get``.
+
+    Each level emits the spans ``repro.mine.join`` (the numpy join, padding
+    and upload), ``repro.mine.count`` (the count launch and keep mask;
+    stats ``rows``, the real candidates, and ``padded``, the rows counted)
+    and ``repro.mine.sync`` (the fetch); the bookkeeping after the fetch
+    lies outside all three.
     """
     results = {1: level1}
     frequent = level1.symbols
     for level in range(2, cfg.max_level + 1):
         if frequent.shape[0] == 0:
             break
-        cands, sym, lo, hi = _padded_level_batch(frequent, level, cfg)
+        with obs.span("mine.join", level=level):
+            cands, sym, lo, hi = _padded_level_batch(frequent, level, cfg)
         b = cands.shape[0]
         if b == 0:
             results[level] = LevelArrays(
                 np.zeros((0, level), np.int32), np.zeros((0,), np.int32), 0)
             break
         thr = (cfg.level_thresholds or {}).get(level, cfg.threshold)
-        counts_dev, checks = count_level(sym, lo, hi)
-        keep_dev = counts_dev >= jnp.int32(thr)             # pruned on device
-        # staticcheck: disable=REPRO004 -- THE sanctioned one-sync-per-level
-        fetched = jax.device_get(
-            (counts_dev[:b], keep_dev[:b])
-            + tuple(flags[:b] for _, flags in checks))
+        with obs.span("mine.count", level=level, rows=b,
+                      padded=sym.shape[0]):
+            counts_dev, checks = count_level(sym, lo, hi)
+            keep_dev = counts_dev >= jnp.int32(thr)         # pruned on device
+        with obs.span("mine.sync", level=level):
+            # staticcheck: disable=REPRO004 -- THE sanctioned one-sync-per-level
+            fetched = jax.device_get(
+                (counts_dev[:b], keep_dev[:b])
+                + tuple(flags[:b] for _, flags in checks))
         counts_h, keep_h = fetched[0], fetched[1]
         for (message, _), flags_h in zip(checks, fetched[2:]):
             if bool(np.any(flags_h)):
@@ -303,31 +313,36 @@ def mine_arrays(stream: EventStream, cfg: MinerConfig) -> Dict[int, LevelArrays]
     if cfg.mesh is not None:
         return mine_sharded(stream, cfg)
     cap = _resolve_cap(cfg, stream)
-    table, type_counts = events_lib.type_index(
-        stream.types, stream.times, stream.n_types, cap)   # built ONCE
-    # pad the index ONCE to its capacity class (+inf columns are inert):
-    # every level's counting call then lands exactly on its plan bucket —
-    # zero per-call padding, and streams of nearby lengths share one
-    # cached executable. build_cap keeps overflow semantics at the true
-    # build width (plan.py / DESIGN.md §11).
-    table = plan_mod.pad_width(table, plan_mod.capacity_class(cap), jnp.inf)
+    with obs.span("mine", events=stream.n_events, cap=cap):
+        with obs.span("mine.index"):
+            table, type_counts = events_lib.type_index(
+                stream.types, stream.times, stream.n_types, cap)  # built ONCE
+            # pad the index ONCE to its capacity class (+inf columns are
+            # inert): every level's counting call then lands exactly on its
+            # plan bucket — zero per-call padding, and streams of nearby
+            # lengths share one cached executable. build_cap keeps overflow
+            # semantics at the true build width (plan.py / DESIGN.md §11).
+            table = plan_mod.pad_width(
+                table, plan_mod.capacity_class(cap), jnp.inf)
 
-    # level 1: single-type episodes; count = per-type event count
-    binc = np.asarray(type_counts)                          # level-1 host sync
-    freq_types = np.nonzero(binc >= cfg.threshold)[0].astype(np.int32)
+        # level 1: single-type episodes; count = per-type event count
+        with obs.span("mine.sync", level=1):
+            binc = np.asarray(type_counts)                  # level-1 host sync
+        freq_types = np.nonzero(binc >= cfg.threshold)[0].astype(np.int32)
 
-    def count_level(sym, lo, hi):
-        counts_dev, _, overflow = counting.count_batch_indexed(
-            table, type_counts, sym, lo, hi,
-            engine=cfg.engine, cap_occ=cfg.cap_occ, max_window=cfg.max_window,
-            parallel_schedule=cfg.parallel_schedule,
-            block_next=cfg.block_next, block_prev=cfg.block_prev,
-            window_tiles=cfg.window_tiles, interpret=cfg.interpret,
-            build_cap=cap)
-        return counts_dev, [(_OVERFLOW_MSG, overflow)]
+        def count_level(sym, lo, hi):
+            counts_dev, _, overflow = counting.count_batch_indexed(
+                table, type_counts, sym, lo, hi,
+                engine=cfg.engine, cap_occ=cfg.cap_occ,
+                max_window=cfg.max_window,
+                parallel_schedule=cfg.parallel_schedule,
+                block_next=cfg.block_next, block_prev=cfg.block_prev,
+                window_tiles=cfg.window_tiles, interpret=cfg.interpret,
+                build_cap=cap)
+            return counts_dev, [(_OVERFLOW_MSG, overflow)]
 
-    return _mine_levels(
-        cfg, _prune_level(freq_types, binc, stream.n_types), count_level)
+        return _mine_levels(
+            cfg, _prune_level(freq_types, binc, stream.n_types), count_level)
 
 
 def mine_sharded(stream: EventStream, cfg: MinerConfig) -> Dict[int, LevelArrays]:
@@ -348,31 +363,35 @@ def mine_sharded(stream: EventStream, cfg: MinerConfig) -> Dict[int, LevelArrays
         raise ValueError("mine_sharded requires cfg.mesh")
     n_shards = (cfg.mesh.shape[cfg.shard_axis] if cfg.n_shards is None
                 else cfg.n_shards)
-    ty, tm = distributed.shard_stream(stream.types, stream.times, n_shards)
-    index = distributed.build_sharded_index(
-        jnp.asarray(ty), jnp.asarray(tm), cfg.mesh, axis=cfg.shard_axis,
-        n_types=stream.n_types, halo=cfg.halo)
+    with obs.span("mine", events=stream.n_events, shards=n_shards):
+        with obs.span("mine.index"):
+            ty, tm = distributed.shard_stream(stream.types, stream.times,
+                                              n_shards)
+            index = distributed.build_sharded_index(
+                jnp.asarray(ty), jnp.asarray(tm), cfg.mesh,
+                axis=cfg.shard_axis, n_types=stream.n_types, halo=cfg.halo)
 
-    binc = np.asarray(index.global_type_counts)             # level-1 host sync
-    freq_types = np.nonzero(binc >= cfg.threshold)[0].astype(np.int32)
-    halo_msg = ("halo too short for the candidate episodes' max_span; "
-                f"raise MinerConfig.halo (got {index.halo} events of "
-                "lookahead per shard)")
+        with obs.span("mine.sync", level=1):
+            binc = np.asarray(index.global_type_counts)     # level-1 host sync
+        freq_types = np.nonzero(binc >= cfg.threshold)[0].astype(np.int32)
+        halo_msg = ("halo too short for the candidate episodes' max_span; "
+                    f"raise MinerConfig.halo (got {index.halo} events of "
+                    "lookahead per shard)")
 
-    def count_level(sym, lo, hi):
-        counts_dev, _, short_dev, overflow_dev = (
-            distributed.count_sharded_batch_indexed(
-                index, sym, lo, hi,
-                engine=cfg.engine, cap_occ=cfg.cap_occ,
-                max_window=cfg.max_window,
-                parallel_schedule=cfg.parallel_schedule,
-                block_next=cfg.block_next, block_prev=cfg.block_prev,
-                window_tiles=cfg.window_tiles, interpret=cfg.interpret))
-        return counts_dev, [(_OVERFLOW_MSG, overflow_dev),
-                            (halo_msg, short_dev)]
+        def count_level(sym, lo, hi):
+            counts_dev, _, short_dev, overflow_dev = (
+                distributed.count_sharded_batch_indexed(
+                    index, sym, lo, hi,
+                    engine=cfg.engine, cap_occ=cfg.cap_occ,
+                    max_window=cfg.max_window,
+                    parallel_schedule=cfg.parallel_schedule,
+                    block_next=cfg.block_next, block_prev=cfg.block_prev,
+                    window_tiles=cfg.window_tiles, interpret=cfg.interpret))
+            return counts_dev, [(_OVERFLOW_MSG, overflow_dev),
+                                (halo_msg, short_dev)]
 
-    return _mine_levels(
-        cfg, _prune_level(freq_types, binc, stream.n_types), count_level)
+        return _mine_levels(
+            cfg, _prune_level(freq_types, binc, stream.n_types), count_level)
 
 
 def mine(stream: EventStream, cfg: MinerConfig) -> Dict[int, LevelResult]:

@@ -146,6 +146,7 @@ def window_truncated(
         lo_idx, hi_idx, cap, block_prev, window_tiles))
 
 
+@jax.named_scope("scan_table")
 def window_scan_table(
     times_by_sym: jax.Array,    # f32[B, N, cap] sorted rows, +inf padded
     t_high: jax.Array,          # f32[B, N-1]
@@ -160,7 +161,8 @@ def window_scan_table(
     a per-episode ``bool[B]`` truncation flag. With ``window_tiles > 0`` the
     scan lengths are capped and any episode whose conservative span bound
     (``span // BP + 2``, the same formula the per-level engine checks) may
-    exceed the cap is flagged — capping is *reported*, never silent.
+    exceed the cap is flagged — capping is *reported*, never silent. Its
+    operations carry the named scope ``scan_table`` in the HLO metadata.
     """
     cap = times_by_sym.shape[-1]
     prev_tiles = cap // block_prev
