@@ -13,9 +13,10 @@ sublane rows of ``W`` lanes, ``W`` = 128 on the chip):
 
 * the per-(row, level, next-tile) first prev tile and exact tile count come
   from a scan table computed outside the kernel (``ops.window_scan_table``
-  — the paper's per-type index made block-level) and arrive as a per-row
-  SMEM block, so every read is a scalar-offset ``pl.ds`` slice of a ref —
-  no vector gathers;
+  — the paper's per-type index made block-level: counts of the prev row's
+  tile boundaries below each next tile's window bounds, no binary search)
+  and arrive as a per-row SMEM block, so every read is a scalar-offset
+  ``pl.ds`` slice of a ref — no vector gathers;
 * for a next sub-row of ``W`` events, its exact window edges
   (``core.episodes.sub_ceil``) are transposed once into columns, and each
   prev sub-row it must scan is a ``(1, W)`` row: the window test is a

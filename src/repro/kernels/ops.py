@@ -16,10 +16,14 @@ multiple (+inf times / -inf values — a max-accumulation no-op).
 refuses tiles Mosaic cannot lay out, and every launch on the chip is sized
 against :data:`VMEM_BUDGET_BYTES` before it reaches the compiler.
 
-The window-span math (`searchsorted` over next-tile extrema) is shared by
-the static host-side bounds (:func:`required_window_tiles`,
-:func:`required_window_tiles_batch`) and the traced batched precompute
-(:func:`window_scan_table`) through :func:`_tile_spans`.
+Each next tile's window query bounds (:func:`_tile_queries`, from the
+tile's extrema) feed two formulations. The traced scan table
+(:func:`window_scan_table`) needs prev *tiles* only, so it counts the prev
+rows' tile boundaries below each bound: a broadcast compare and a sum, no
+gather and no loop. Exact prev-*event* spans (:func:`_tile_spans`,
+``searchsorted``) serve the static host-side bounds
+(:func:`required_window_tiles`, :func:`required_window_tiles_batch`) and
+every truncation flag of a ``window_tiles`` cap.
 """
 from __future__ import annotations
 
@@ -69,6 +73,19 @@ def _tile_spans(
     padding tiles (min = +inf) report ``has = False``. Returns
     ``(lo_idx, hi_idx, has)`` each shaped ``[..., next_tiles]``.
     """
+    q_lo, q_hi, has = _tile_queries(t_next, t_high, block_next)
+    lo_idx = _searchsorted_rows(t_prev, q_lo)
+    hi_idx = _searchsorted_rows(t_prev, q_hi)
+    return lo_idx.astype(jnp.int32), hi_idx.astype(jnp.int32), has
+
+
+def _tile_queries(
+    t_next: jax.Array, t_high, block_next: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Per next tile the window's query bounds ``(q_lo, q_hi, has)``: prev
+    events ``s`` with ``q_lo <= s < q_hi`` can fall in some window of the
+    tile (``q_lo = sub_ceil(tile min, t_high)``, ``q_hi`` = finite tile
+    max), and ``has`` marks tiles holding an event. Each ``[..., NT]``."""
     nt = t_next.shape[-1] // block_next
     tiles = t_next[..., : nt * block_next].reshape(
         t_next.shape[:-1] + (nt, block_next))
@@ -77,9 +94,15 @@ def _tile_spans(
     tile_max = jnp.max(jnp.where(finite, tiles, -jnp.inf), axis=-1)
     has = finite[..., 0]
     t_high = jnp.asarray(t_high, jnp.float32)[..., None]
-    lo_idx = _searchsorted_rows(t_prev, sub_ceil(tile_min, t_high))
-    hi_idx = _searchsorted_rows(t_prev, tile_max)
-    return lo_idx.astype(jnp.int32), hi_idx.astype(jnp.int32), has
+    return sub_ceil(tile_min, t_high), tile_max, has
+
+
+def _count_below(bounds: jax.Array, q: jax.Array) -> jax.Array:
+    """``#{k : bounds[..., k] < q[..., j]}`` per query, ``i32[..., NT]``:
+    a broadcast compare and a sum, no gather. A NaN query counts every
+    bound, as the sort order of ``jnp.searchsorted`` places NaN last."""
+    below = (bounds[..., None, :] < q[..., None]) | jnp.isnan(q)[..., None]
+    return jnp.sum(below, axis=-1, dtype=jnp.int32)
 
 
 def required_window_tiles(
@@ -158,20 +181,30 @@ def window_scan_table(
 
     Returns ``(start_tile, num_tiles, truncated)``: the first prev tile and
     exact tile count each next tile must scan (both ``i32[B, N-1, NT]``) and
-    a per-episode ``bool[B]`` truncation flag. With ``window_tiles > 0`` the
-    scan lengths are capped and any episode whose conservative span bound
-    (``span // BP + 2``, the same formula the per-level engine checks) may
-    exceed the cap is flagged — capping is *reported*, never silent. Its
+    a per-episode ``bool[B]`` truncation flag. Both tile numbers are counts
+    of prev-tile boundaries below the window's query bounds. With
+    ``window_tiles > 0`` the scan lengths are capped and any episode whose
+    conservative span bound (``span // BP + 2``, the same formula the
+    per-level engine checks) may exceed the cap is flagged — capping is
+    *reported*, never silent; only this flag searches for event spans. Its
     operations carry the named scope ``scan_table`` in the HLO metadata.
     """
     cap = times_by_sym.shape[-1]
     prev_tiles = cap // block_prev
-    lo_idx, hi_idx, has = _tile_spans(
-        times_by_sym[:, :-1], times_by_sym[:, 1:], t_high, block_next)
-    start = lo_idx // block_prev
-    end = (hi_idx + block_prev - 1) // block_prev
+    t_prev = times_by_sym[:, :-1]
+    q_lo, q_hi, has = _tile_queries(times_by_sym[:, 1:], t_high, block_next)
+    # On sorted rows, lo_idx // BP counts the prev tiles whose last event
+    # lies below q_lo, and ceil(hi_idx / BP) the prev tiles whose first
+    # event lies below q_hi: the tile boundaries alone give both.
+    last = jax.lax.slice_in_dim(t_prev, block_prev - 1, cap, block_prev, -1)
+    first = jax.lax.slice_in_dim(t_prev, 0, cap, block_prev, -1)
+    start = _count_below(last, q_lo)
+    end = _count_below(first, q_hi)
     num = jnp.where(has, jnp.maximum(end - start, 0), 0)
     if 0 < window_tiles < prev_tiles:
+        # the flag bounds exact event spans, which tile counts cannot give
+        lo_idx, hi_idx, _ = _tile_spans(
+            t_prev, times_by_sym[:, 1:], t_high, block_next)
         truncated = jnp.any(window_span_exceeds(
             lo_idx, hi_idx, cap, block_prev, window_tiles), axis=(1, 2))
         num = jnp.minimum(num, window_tiles)

@@ -10,9 +10,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.staticcheck.jaxpr_checks import iter_primitives
 from repro.core import count_fsm_numpy, count_nonoverlapped, serial
 from repro.core.counting import count_batch_indexed
-from repro.core.episodes import episode_batch
+from repro.core.episodes import episode_batch, sub_ceil
 from repro.core.events import EventStream, type_index
 from repro.kernels import ops, ref
 
@@ -130,6 +131,96 @@ def test_required_window_tiles_batch_covers_each_level():
         window_tiles=0, interpret=True)
     np.testing.assert_array_equal(
         np.asarray(starts_full), np.asarray(starts_cap))
+
+
+# ---------------------------------------------------------------------------
+# Window scan table: tile-boundary counts vs the binary-search formulation
+# ---------------------------------------------------------------------------
+
+
+def _scan_table_oracle(times, t_high, bn, bp, window_tiles):
+    """The scan table as binary searches: each next tile's event span
+    ``[lo_idx, hi_idx)`` by ``searchsorted`` over the prev row, then
+    ``lo_idx // bp`` and ``ceil(hi_idx / bp)``."""
+    b, n, cap = times.shape
+    nt, prev_tiles = cap // bn, cap // bp
+    tiles = times[..., : nt * bn].reshape(b, n, nt, bn)
+    finite = np.isfinite(tiles)
+    has = finite[:, 1:, :, 0]
+    q_lo = np.asarray(sub_ceil(tiles[:, 1:, :, 0], t_high[..., None]))
+    q_hi = np.where(finite, tiles, -np.inf).max(axis=-1)[:, 1:]
+    lo_idx = np.zeros((b, n - 1, nt), np.int64)
+    hi_idx = np.zeros((b, n - 1, nt), np.int64)
+    for i in range(b):
+        for s in range(n - 1):
+            lo_idx[i, s] = np.searchsorted(times[i, s], q_lo[i, s], "left")
+            hi_idx[i, s] = np.searchsorted(times[i, s], q_hi[i, s], "left")
+    start = lo_idx // bp
+    num = np.where(has, np.maximum(-(-hi_idx // bp) - start, 0), 0)
+    truncated = np.zeros(b, bool)
+    if 0 < window_tiles < prev_tiles:
+        span = np.clip(hi_idx - lo_idx, 0, cap)
+        truncated = (span // bp + 2 > window_tiles).any(axis=(1, 2))
+        num = np.minimum(num, window_tiles)
+    return np.clip(start, 0, max(prev_tiles - 1, 0)), num, truncated
+
+
+def _scan_table_case(kind, cap, rng):
+    b, n = 4, 3
+    if kind == "ties":
+        # integer times over a short range: runs of duplicates, and tile
+        # boundaries equal to the integer window edges of other rows
+        times = np.full((b, n, cap), np.inf, np.float32)
+        for i in range(b):
+            for s in range(n):
+                k = int(rng.integers(cap // 2, cap + 1))
+                times[i, s, :k] = np.sort(rng.integers(0, cap // 4, k))
+        return times, rng.integers(1, 4, (b, n - 1)).astype(np.float32)
+    empty = {(1, 1)}
+    if kind == "all_inf":
+        empty |= {(0, 0), (0, 1), (0, 2), (2, 0), (3, 2)}
+    times = _batch_times(rng, b, n, cap, empty_rows=empty)
+    if kind == "t_high_0":
+        return times, np.zeros((b, n - 1), np.float32)
+    if kind == "t_high_inf":   # padding tiles get NaN edges (inf - inf)
+        return times, np.full((b, n - 1), np.inf, np.float32)
+    return times, rng.choice([0.5, 10.0, 50.0], (b, n - 1)).astype(np.float32)
+
+
+@pytest.mark.parametrize("window_tiles", [0, 1, 3])
+@pytest.mark.parametrize("kind", ["random", "ties", "all_inf", "t_high_0",
+                                  "t_high_inf"])
+@pytest.mark.parametrize("blocks", [(8, 8), (16, 8), (8, 16), (4, 12),
+                                    (128, 128)])
+def test_window_scan_table_matches_binary_search(blocks, kind, window_tiles):
+    bn, bp = blocks
+    cap = 4 * np.lcm(bn, bp)
+    rng = np.random.default_rng(bn * 1000 + bp + window_tiles)
+    times, t_high = _scan_table_case(kind, cap, rng)
+    got = ops.window_scan_table(jnp.asarray(times), jnp.asarray(t_high),
+                                bn, bp, window_tiles)
+    want = _scan_table_oracle(times, t_high, bn, bp, window_tiles)
+    for g, w in zip(got, want):
+        assert g.dtype == (jnp.bool_ if w.dtype == bool else jnp.int32)
+        np.testing.assert_array_equal(np.asarray(g), w)
+    if window_tiles == 1 and kind == "random":
+        assert want[2].any()     # the capped path still flags truncation
+
+
+def test_window_scan_table_default_path_has_no_search():
+    """At the chip's shape the default table is compares and sums: no
+    loop (``jnp.searchsorted`` traces to a ``scan``, lowered to an HLO
+    ``while``) and no ``gather`` anywhere in its jaxpr."""
+    def prims(window_tiles):
+        closed = jax.make_jaxpr(
+            lambda t, h: ops.window_scan_table(t, h, 128, 128, window_tiles))(
+            jax.ShapeDtypeStruct((8, 2, 8192), jnp.float32),
+            jax.ShapeDtypeStruct((8, 1), jnp.float32))
+        return {name for name, _ in iter_primitives(closed.jaxpr)}
+    searches = {"while", "scan", "gather"}
+    assert not prims(0) & searches
+    # the capped path keeps its binary search for the truncation flag
+    assert {"scan", "gather"} <= prims(4)
 
 
 # ---------------------------------------------------------------------------
