@@ -1,7 +1,8 @@
 """Device time per recording of the operations under the named scope
-``scan_table`` (ms): the fused kernel's window scan tables, the binary
-searches over the ``[B, N, cap]`` rows, outside the ``count_batch``
-kernel."""
+``scan_table`` (ms): the fused kernel's window scan tables, counts of the
+prev rows' tile boundaries below each next tile's window bounds (a
+compare and a sum per side), in the plan cache's programs, outside the
+``count_batch`` kernel."""
 from benchmarks.chip import spans
 
 

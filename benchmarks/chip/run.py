@@ -203,6 +203,10 @@ def read_trace(ctx: Context, driver, state, device, win: Window):
     ctx.log(f"trace: {reading.units} units, window {tr.window_s} s, busy "
             f"{device['busy_s']} s, kernel {reading.kernel_s()} s, "
             f"{len(tr.ops())} device operations")
+    if not tr.modules:
+        ctx.log(f"trace: no {trace_lib.MODULES_LINE!r} line on the device "
+                "planes; scope metrics count operations by name in every "
+                "program")
     metrics = {}
     for m in ctx.cell.per_layer:
         value = load_module(HERE / "metrics" / f"{m['name']}.py").read(reading)

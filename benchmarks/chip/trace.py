@@ -12,7 +12,12 @@ on a host plane on the same clock, on the thread that drives the program.
 * a kernel's time: the union of the intervals of the operations whose name
   names the kernel;
 * idle gaps: the stretches of the window in which no operation ran,
-  attributed to the host span that covers each gap's midpoint.
+  attributed to the host span that covers each gap's midpoint;
+* an operation's module: the program it ran in, the event of the device
+  plane's ``XLA Modules`` line that covers the operation's start (named
+  ``<HloModule name>(<program id>)`` on a TPU);
+* a host event's stats: the keyword arguments of its
+  ``TraceAnnotation`` (``rows``, ``padded``, ...).
 """
 from __future__ import annotations
 
@@ -20,7 +25,7 @@ import dataclasses
 import glob
 import os
 import re
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -28,6 +33,7 @@ Interval = Tuple[int, int]           # (start_ns, end_ns)
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."               # the benchmark's own host spans
 WINDOW_SPAN = "bench.window"
 
@@ -37,6 +43,27 @@ class Event:
     name: str
     start_ns: int
     end_ns: int
+    stats: Tuple[Tuple[str, Any], ...] = ()    # host events: their stats
+    module: str = ""          # device operations: the program they ran in
+
+
+def module_name(name: str) -> str:
+    """The HloModule name of a module event's name: a TPU trace appends
+    the program id (``jit_fn(10399301410868591309)``)."""
+    return re.sub(r"\(\d+\)$", "", name)
+
+
+def in_modules(ops: Sequence[Event], modules: Sequence[Event]) -> List[Event]:
+    """``ops`` with each one's ``module`` set to the name of the event of
+    ``modules`` that covers its start ("" where none does). A device runs
+    one program at a time, so its module events do not overlap."""
+    mods = sorted(modules, key=lambda m: m.start_ns)
+    starts = np.array([m.start_ns for m in mods], np.int64)
+    at = np.searchsorted(starts, [e.start_ns for e in ops], side="right") - 1
+    return [dataclasses.replace(
+                e, module=mods[k].name if k >= 0 and e.start_ns < mods[k].end_ns
+                else "")
+            for e, k in zip(ops, at.tolist())]
 
 
 def op_name(name: str) -> str:
@@ -97,6 +124,7 @@ class Trace:
     spans: List[Event]                       # the benchmark's host spans
     host: List[Event] = dataclasses.field(default_factory=list)
                                              # other events of their thread
+    modules: bool = False                    # device ops carry their module
 
     @property
     def window_s(self) -> float:
@@ -104,6 +132,13 @@ class Trace:
 
     def ops(self) -> List[Event]:
         return [e for evs in self.device_ops.values() for e in evs]
+
+    def only(self, keep) -> "Trace":
+        """This trace with only the device operations that ``keep`` (a
+        predicate on an ``Event``) accepts."""
+        return dataclasses.replace(self, device_ops={
+            plane: [e for e in evs if keep(e)]
+            for plane, evs in self.device_ops.items()})
 
     def busy_s(self, match=None) -> float:
         """Busy seconds per device, averaged over the devices traced,
@@ -179,7 +214,9 @@ def read(path: str, *, device_plane=DEVICE_PLANE,
          ops_line: str = OPS_LINE) -> Trace:
     """Read an ``.xplane.pb``: the device operations of every plane whose
     name matches ``device_plane`` (on its lines whose name starts with
-    ``ops_line``), and the benchmark's host spans. The window is the
+    ``ops_line``), each with its module where the plane has a
+    ``XLA Modules`` line, and the benchmark's host spans with the other
+    events of their thread, stats included. The window is the
     ``bench.window`` span, or the extent of the device operations when the
     trace has none."""
     from jax.profiler import ProfileData
@@ -188,21 +225,31 @@ def read(path: str, *, device_plane=DEVICE_PLANE,
     device_ops: Dict[str, List[Event]] = {}
     spans: List[Event] = []
     host: List[Event] = []
+    has_modules = False
     for plane in data.planes:
         is_device = bool(device_plane.match(plane.name))
+        modules: List[Event] = []
         for line in plane.lines:
             if is_device and line.name.startswith(ops_line):
                 device_ops.setdefault(plane.name, []).extend(
                     Event(op_name(e.name), int(e.start_ns), int(e.end_ns))
                     for e in line.events)
                 continue
-            events = [Event(e.name, int(e.start_ns), int(e.end_ns))
-                      for e in line.events]
-            ours = [e for e in events if e.name.startswith(SPAN_PREFIX)]
-            if ours:          # the thread that drives the program
-                spans.extend(ours)
-                host.extend(e for e in events
-                            if not e.name.startswith(SPAN_PREFIX))
+            if is_device and line.name == MODULES_LINE:
+                modules.extend(Event(e.name, int(e.start_ns), int(e.end_ns))
+                               for e in line.events)
+                continue
+            events = list(line.events)
+            if not any(e.name.startswith(SPAN_PREFIX) for e in events):
+                continue
+            for e in events:          # the thread that drives the program
+                ev = Event(e.name, int(e.start_ns), int(e.end_ns),
+                           tuple((k, v) for k, v in e.stats))
+                (spans if e.name.startswith(SPAN_PREFIX) else host).append(ev)
+        if modules and plane.name in device_ops:
+            device_ops[plane.name] = in_modules(device_ops[plane.name],
+                                                modules)
+            has_modules = True
     window: Optional[Interval] = None
     for s in spans:
         if s.name == WINDOW_SPAN:
@@ -212,4 +259,5 @@ def read(path: str, *, device_plane=DEVICE_PLANE,
         window = ((min(e.start_ns for e in ops), max(e.end_ns for e in ops))
                   if ops else (0, 0))
     return Trace(window, device_ops,
-                 [s for s in spans if s.name != WINDOW_SPAN], host)
+                 [s for s in spans if s.name != WINDOW_SPAN], host,
+                 has_modules)

@@ -17,6 +17,7 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -122,6 +123,43 @@ def test_cpu_recorded_trace_busy_union_and_kernel_names(tmp_path):
     assert any(re.match(r"^sort(\.\d+)?$", e.name) for e in trace.ops())
 
 
+def test_device_ops_keep_the_module_that_covers_their_start():
+    mods = [tl.Event("jit_fn(53)", 100, 200),
+            tl.Event("jit_scatter_add(12)", 0, 50)]
+    ops = [tl.Event("fusion", 10, 20), tl.Event("fusion", 100, 150),
+           tl.Event("copy.1", 190, 230), tl.Event("fusion", 60, 70),
+           tl.Event("fusion", 200, 210)]
+    assert [e.module for e in tl.in_modules(ops, mods)] == [
+        "jit_scatter_add(12)", "jit_fn(53)", "jit_fn(53)", "", ""]
+    assert [e.module for e in tl.in_modules(ops, [])] == [""] * 5
+    assert tl.module_name("jit_fn(10399301410868591309)") == "jit_fn"
+    assert tl.module_name("jit_scatter-add") == "jit_scatter-add"
+
+
+def test_cpu_recorded_trace_keeps_span_stats(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.arange(1000.0)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation(tl.WINDOW_SPAN):
+        with jax.profiler.TraceAnnotation("bench.step"):
+            with jax.profiler.TraceAnnotation("repro.mine.count", rows=12,
+                                              padded=16):
+                jnp.cumsum(x).block_until_ready()
+    jax.profiler.stop_trace()
+
+    trace = tl.read(tl.find_xplane(str(tmp_path)),
+                    device_plane=re.compile(r"^/host:CPU$"), ops_line="tf_XLA")
+    counts = [e for e in trace.host if e.name == "repro.mine.count"]
+    assert [list(e.stats) for e in counts] == [[("rows", 12), ("padded", 16)]]
+    assert [s.stats for s in trace.spans] == [()]
+    hash(counts[0])                         # an Event stays hashable
+    assert not trace.modules                # the CPU has no modules line
+
+
 # ---------------------------------------------------------------------------
 # the roofline's numerator
 # ---------------------------------------------------------------------------
@@ -222,6 +260,8 @@ def small_cell(name):
     cell.config["network"].update(n_neurons=8, n_embedded=1, episode_len=4)
     cell.config["mining"].update(max_level=3, max_candidates=64)
     cell.traffic = dict(cell.traffic, recording_s=3.0, recordings=2)
+    if "sessions" in cell.traffic:
+        cell.traffic["sessions"] = 2
     return cell
 
 
@@ -262,41 +302,97 @@ def _drop_half(counts, *rest):
     return (counts.at[..., half:].set(0), *rest)
 
 
+# The program's count entries: every entry point (mining.py, streaming.py,
+# corpus.py, distributed.py, serving.py) calls one of them as
+# ``counting.<name>``, and each returns the counts first.
+COUNT_ENTRIES = ("count_batch_indexed", "count_batch_indexed_stateful",
+                 "count_tail_batch_indexed", "count_corpus_indexed",
+                 "count_corpus_tail_grouped")
+
+
+def break_counts(monkeypatch, fault, when=lambda: True):
+    """Wrap every entry of ``COUNT_ENTRIES`` so that ``fault`` alters its
+    counts (while ``when()`` holds); returns the calls made, by entry."""
+    from repro.core import counting
+
+    calls = dict.fromkeys(COUNT_ENTRIES, 0)
+
+    def wrap(name):
+        real = getattr(counting, name)
+
+        def entry(*a, **k):
+            calls[name] += 1
+            out = real(*a, **k)
+            return fault(*out) if when() else out
+        return entry
+
+    for name in COUNT_ENTRIES:
+        monkeypatch.setattr(counting, name, wrap(name))
+    return calls
+
+
+def assert_reached_an_entry(name, calls):
+    """A cell whose timed path calls none of the wrapped entries would
+    pass the fault tests without their fault: name the gap instead."""
+    if not any(calls.values()):
+        raise AssertionError(
+            f"the small run of {name} reached none of COUNT_ENTRIES "
+            f"{COUNT_ENTRIES}: add the count entry its path calls")
+
+
 @pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("fault", [_alter_answer, _drop_half])
 def test_broken_timed_path_is_not_correct(monkeypatch, name, fault):
-    from repro.core import counting
-
-    real = counting.count_batch_indexed
-    monkeypatch.setattr(counting, "count_batch_indexed",
-                        lambda *a, **k: fault(*real(*a, **k)))
+    calls = break_counts(monkeypatch, fault)
     result = run_small(name)
+    assert_reached_an_entry(name, calls)
     assert not result["correct"]
     assert all(result["checks"][c]["value"] > 0 for c in CHECKS)
 
 
-def test_fault_after_the_window_fails_the_fresh_recording(monkeypatch):
+def test_path_that_reaches_no_count_entry_is_named(monkeypatch):
+    """A driver whose run calls none of ``COUNT_ENTRIES`` fails the fault
+    tests' precondition with a message naming them."""
+    from benchmarks.chip.harness import Window
+
+    fake = SimpleNamespace(
+        setup=lambda ctx: None,
+        window=lambda ctx, st: Window(metrics={"mine_events_per_s": 1.0},
+                                      attempted=1, failed=0, compiles=0),
+        check=lambda ctx, st: {c: (0, 0) for c in CHECKS})
+    real_load = run.load_module
+    monkeypatch.setattr(run, "load_module", lambda path: fake
+                        if path.name == "fake.py" else real_load(path))
+    cell = small_cell("batch-ds8-20s")
+    cell.config["driver"] = "fake"
+    calls = break_counts(monkeypatch, _alter_answer)
+    result = run.run_cell(cell, seed=5, seconds=0.1, trace=False,
+                          require_tpu=False, log=lambda _: None,
+                          t_start=time.perf_counter())
+    assert result["correct"]
+    with pytest.raises(AssertionError, match="COUNT_ENTRIES"):
+        assert_reached_an_entry(cell.name, calls)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_fault_after_the_window_fails_the_fresh_recording(monkeypatch, name):
     """A count that goes wrong only after the window (as a program that
     breaks on a stream length it has not seen would) is caught by the
     fresh recording alone."""
-    from repro.core import counting
-
-    driver = run.load_module(run.HERE / "drivers" / "mine_arrays.py")
+    driver = run.load_module(
+        run.HERE / "drivers" / f"{small_cell(name).config['driver']}.py")
     broken = {"on": False}
-    real_count, real_window = counting.count_batch_indexed, driver.window
-
-    def count(*a, **k):
-        out = real_count(*a, **k)
-        return _alter_answer(*out) if broken["on"] else out
+    calls = break_counts(monkeypatch, _alter_answer, lambda: broken["on"])
+    real_window = driver.window
 
     def window(*a, **k):
         win = real_window(*a, **k)
         broken["on"] = True
         return win
 
-    monkeypatch.setattr(counting, "count_batch_indexed", count)
     monkeypatch.setattr(driver, "window", window)
-    result = run_small("batch-ds8-20s")
+    result = run_small(name)
+    assert_reached_an_entry(name, calls)
     assert not result["correct"]
     assert result["checks"]["mismatched_entries"]["value"] == 0
     assert result["checks"]["fresh_recording_mismatched_entries"]["value"] > 0

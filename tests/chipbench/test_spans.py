@@ -1,5 +1,5 @@
 """Tests of the readers of the program's own spans and scopes
-(``benchmarks/chip/spans.py`` and its seven metrics).
+(``benchmarks/chip/spans.py`` and its eight metrics).
 
 Each reader is checked on a hand-built trace against a hand count, made to
 fail where units were traced and its span or scope is absent, and made to
@@ -9,6 +9,7 @@ readers against the result they describe.
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 
 import numpy as np
@@ -24,6 +25,8 @@ SPAN_READERS = {               # metric -> its value on the hand-built trace
     "count_host_ms.batch": (3 + 2) * 1e-6 / 2,
     "sync_wait_ms.batch": (5 + 7 + 5) * 1e-6 / 2,
     "syncs_per_recording.batch": 3 / 2,
+    # rows / padded of the two count spans: (60 + 3) / (64 + 16)
+    "count_rows_useful_pct.batch": 100.0 * 63 / 80,
 }
 SCOPE_READERS = {
     # fusion.2 [100, 130) and gather.1 [125, 140): union 40 ns; the kernel
@@ -46,15 +49,21 @@ ENTRY %main.1 (table.1: f32[8,16]) -> s32[4] {
 """
 
 
-def hand_trace(drop=()):
+def hand_trace(drop=(), count_stats=("rows", "padded")):
     """Two recordings' worth of host spans and device operations (ns)."""
-    host = [tl.Event(n, a, b) for n, a, b in [
-        ("repro.mine", 0, 60), ("repro.mine.index", 1, 11),
-        ("DevicePut", 2, 4),                          # a JAX event inside
-        ("repro.mine.sync", 11, 16), ("repro.mine.join", 17, 23),
-        ("repro.mine.count", 23, 26), ("repro.mine.sync", 26, 33),
-        ("repro.mine.join", 40, 44), ("repro.mine.count", 44, 46),
-        ("repro.mine.sync", 46, 51)] if n not in drop]
+    def count(a, b, rows, padded):
+        stats = {"level": 2, "rows": rows, "padded": padded}
+        return tl.Event("repro.mine.count", a, b, tuple(
+            (k, v) for k, v in stats.items()
+            if k == "level" or k in count_stats))
+
+    host = [e for e in [
+        tl.Event("repro.mine", 0, 60), tl.Event("repro.mine.index", 1, 11),
+        tl.Event("DevicePut", 2, 4),                  # a JAX event inside
+        tl.Event("repro.mine.sync", 11, 16), tl.Event("repro.mine.join", 17, 23),
+        count(23, 26, 60, 64), tl.Event("repro.mine.sync", 26, 33),
+        tl.Event("repro.mine.join", 40, 44), count(44, 46, 3, 16),
+        tl.Event("repro.mine.sync", 46, 51)] if e.name not in drop]
     ops = [tl.Event(n, a, b) for n, a, b in [
         ("fusion.2", 100, 130), ("gather.1", 125, 140),
         ("count_batch.1", 130, 160), ("while.15", 160, 200),
@@ -104,12 +113,64 @@ def test_scopes_stay_within_other_device_time(hlo):
     ("count_host_ms.batch", "repro.mine.count"),
     ("sync_wait_ms.batch", "repro.mine.sync"),
     ("syncs_per_recording.batch", "repro.mine.sync"),
+    ("count_rows_useful_pct.batch", "repro.mine.count"),
 ])
 def test_span_reader_fails_when_its_span_is_absent(hlo, name, absent):
     read = reader(name)
     assert read(reading(hand_trace(drop=[absent]), units=0)) is None
     with pytest.raises(LookupError, match=re.escape(absent)):
         read(reading(hand_trace(drop=[absent])))
+
+
+@pytest.mark.parametrize("stat", ["rows", "padded"])
+def test_span_stat_sum_hand_counted_and_fails_without_the_stat(stat):
+    r = reading(hand_trace())
+    want = {"rows": 60 + 3, "padded": 64 + 16}[stat]
+    assert spans.span_stat_sum(r, "repro.mine.count", stat) == want
+    # only the spans that start inside the traced window count
+    late = dataclasses.replace(r.trace, window=(30, 300))
+    assert spans.span_stat_sum(reading(late), "repro.mine.count", stat) == \
+        {"rows": 3, "padded": 16}[stat]
+    lacking = hand_trace(count_stats=[s for s in ("rows", "padded")
+                                      if s != stat])
+    with pytest.raises(LookupError, match=stat):
+        spans.span_stat_sum(reading(lacking), "repro.mine.count", stat)
+    with pytest.raises(LookupError, match=stat):
+        reader("count_rows_useful_pct.batch")(reading(lacking))
+    assert spans.span_stat_sum(reading(lacking, units=0),
+                               "repro.mine.count", stat) == 0
+
+
+PLAN_HLO = """HloModule jit_fn, is_scheduled=true, entry_computation_layout={(f32[8,16]{1,0})->s32[4]{0}}
+
+ENTRY %main.1 (table.1: f32[8,16]) -> s32[4] {
+  %fusion = f32[4,2,16]{2,1,0} fusion(%table.1), kind=kLoop, metadata={op_name="jit(fn)/table_gather/gather"}
+  ROOT %count_batch.1 = s32[4] custom-call(%fusion), custom_call_target="tpu_custom_call", metadata={op_name="jit(fn)/count_batch/pallas_call"}
+}
+"""
+
+
+@pytest.mark.parametrize("modules,want_ns", [(True, 30), (False, 30 + 40 + 10)])
+def test_scope_counts_only_the_plan_programs_operations(
+        monkeypatch, modules, want_ns):
+    """An eager module and a plan's both run an operation named
+    ``fusion``: with the modules line only the plan's counts; without it
+    the reader counts by name, as before."""
+    monkeypatch.setattr(spans, "program_hlo", lambda: [PLAN_HLO])
+    ops = [tl.Event("fusion", 100, 130, module="jit_fn(53)"),
+           tl.Event("count_batch.1", 130, 160, module="jit_fn(53)"),
+           tl.Event("fusion", 200, 240, module="jit_scatter_add(12)"),
+           tl.Event("fusion", 250, 260)]          # under no module event
+    if not modules:
+        ops = [dataclasses.replace(e, module="") for e in ops]
+    trace = tl.Trace(window=(0, 300), device_ops={"/device:TPU:0": ops},
+                     spans=[], host=hand_trace().host, modules=modules)
+    assert reader("table_gather_ms.batch")(reading(trace)) == \
+        pytest.approx(want_ns * 1e-6 / 2)
+
+
+def test_module_names_of_the_plan_programs():
+    assert spans.module_names([PLAN_HLO, HLO, PLAN_HLO]) == {"jit_fn"}
 
 
 @pytest.mark.parametrize("name,absent", [
