@@ -42,6 +42,19 @@ every chunk. Looping per-session miners pays the per-dispatch overhead
   Differentially enforced across engines x interleavings x churn in
   ``tests/test_serving.py``.
 
+* **Spans** — each :meth:`StreamingCorpusMiner.flush` that absorbs data
+  emits the profiler span ``repro.pool.flush`` (stats ``sessions``, the
+  dirty sessions, and ``events``, the events absorbed) and, inside it,
+  leaves that never overlap (``core/obs.py``): ``repro.pool.absorb``
+  (coalesce, bincount, cap growth, the chunk matrix, the batched scatter's
+  dispatch, per-session buffers and suffix sizing), then per level
+  ``repro.pool.join`` (every session's candidate join),
+  ``repro.pool.count`` (the warm/cold split, row and carry assembly and
+  the dispatches; stats ``level``, ``warm`` and ``cold``, the real rows of
+  each family, and ``padded``, the rows dispatched), ``repro.pool.sync``
+  (the level's one ``device_get``) and ``repro.pool.carry`` (counts and
+  chain states written back).
+
 :class:`MiningSessionServer` is the serving front-end on top: opaque
 session ids over recycled pool slots (continuous-batching style — the
 ``launch/serve.py`` slot-per-request pattern, claimed for mining), with
@@ -61,6 +74,7 @@ import numpy as np
 
 from . import counting
 from . import events as events_lib
+from . import obs
 from . import plan as plan_mod
 from .mining import (_OVERFLOW_MSG, LevelArrays, MinerConfig, _prune_level,
                      generate_candidates_arrays)
@@ -234,6 +248,16 @@ class StreamingCorpusMiner:
         dirty = [(i, self._slots[i]) for i in self.dirty_slots()]
         if not dirty:
             return
+        events = sum(int(c[0].size) for _, st in dirty for c in st.pending)
+        with obs.span("pool.flush", sessions=len(dirty), events=events):
+            with obs.span("pool.absorb"):
+                tail_cap, old_counts_dev = self._absorb(dirty)
+            self._mine_levels_pool(dirty, tail_cap, old_counts_dev)
+
+    def _absorb(self, dirty):
+        """Scatter every dirty session's coalesced chunk into the pool and
+        update its host state; returns the shared warm tail width and the
+        per-type totals before the chunks."""
         chunks: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         for i, st in dirty:
             ty = np.concatenate([c[0] for c in st.pending])
@@ -297,7 +321,7 @@ class StreamingCorpusMiner:
         # every session's counts stay bit-for-bit its solo miner's
         tail_cap = plan_mod.capacity_class(tail_need, floor=16)
 
-        self._mine_levels_pool(dirty, tail_cap, old_counts_dev)
+        return tail_cap, old_counts_dev
 
     # -- level loop (each session mirrors streaming._mine_levels) ----------
 
@@ -317,20 +341,22 @@ class StreamingCorpusMiner:
 
         for level in range(2, cfg.max_level + 1):
             joined: Dict[int, np.ndarray] = {}
-            for i, st in dirty:
-                if not running[i]:
-                    continue
-                if frontier[i].shape[0] == 0:
-                    running[i] = False                   # quiet: no record
-                    continue
-                cands = generate_candidates_arrays(frontier[i], level, cfg)
-                if cands.shape[0] == 0:
-                    results[i][level] = LevelArrays(
-                        np.zeros((0, level), np.int32),
-                        np.zeros((0,), np.int32), 0)
-                    running[i] = False
-                    continue
-                joined[i] = cands
+            with obs.span("pool.join", level=level):
+                for i, st in dirty:
+                    if not running[i]:
+                        continue
+                    if frontier[i].shape[0] == 0:
+                        running[i] = False               # quiet: no record
+                        continue
+                    cands = generate_candidates_arrays(frontier[i], level,
+                                                       cfg)
+                    if cands.shape[0] == 0:
+                        results[i][level] = LevelArrays(
+                            np.zeros((0, level), np.int32),
+                            np.zeros((0,), np.int32), 0)
+                        running[i] = False
+                        continue
+                    joined[i] = cands
             if not joined:
                 break
             counts_by_slot = self._count_level_pool(
@@ -348,12 +374,12 @@ class StreamingCorpusMiner:
 
         for i, st in dirty:
             st.results = results[i]
-            # evict chain states not advanced through THIS flush (the
-            # streaming warmth rule: stale states can only recount cold)
-            for cache in st.cache.values():
-                stale = [k for k, cs in cache.items() if cs.seq != st.seq]
-                for k in stale:
-                    del cache[k]
+            # a level this flush did not count keeps no chain state (the
+            # streaming warmth rule: stale states can only recount cold);
+            # a counted level's cache holds only this flush's states
+            for level, cache in st.cache.items():
+                if cache and next(iter(cache.values())).seq != st.seq:
+                    st.cache[level] = {}
 
     def _count_level_pool(self, level, joined, t0_vec, tail_cap,
                           old_counts_dev) -> Dict[int, np.ndarray]:
@@ -372,6 +398,43 @@ class StreamingCorpusMiner:
         never read (the ``mine_corpus`` quiet-stream masking rule), and all
         chunks of both families fetch in ONE ``device_get``.
         """
+        with obs.span("pool.count", level=level) as span:
+            keys_of, dispatched, stats = self._dispatch_level(
+                level, joined, t0_vec, tail_cap, old_counts_dev)
+            span.set_metadata(**stats)
+        with obs.span("pool.sync", level=level):
+            fetched = jax.device_get(
+                [[p[1] for p in parts] for parts in dispatched])  # ONE sync
+        with obs.span("pool.carry", level=level):
+            out: Dict[int, np.ndarray] = {
+                i: np.zeros((len(keys_of[i]),), np.int64) for i in joined}
+            states = {i: {} for i in joined}
+            for parts, vals in zip(dispatched, fetched):
+                for (sel, _), (cnt, end, _, ovf, short) in zip(parts, vals):
+                    for i, rows in sel.items():
+                        m = rows.size
+                        if short[i, :m].any():
+                            raise RuntimeError(_TAIL_SHORT_MSG)
+                        if ovf[i, :m].any():
+                            raise RuntimeError(
+                                f"session slot {i}: {_OVERFLOW_MSG}")
+                        seq = self._slots[i].seq
+                        out[i][rows] = cnt[i, :m]
+                        for j, r in enumerate(rows):
+                            states[i][keys_of[i][r]] = _ChainState(
+                                prev_end=float(end[i, j]),
+                                count=int(cnt[i, j]), seq=seq)
+            # the level's cache now holds only the states of this flush
+            for i, level_states in states.items():
+                self._slots[i].cache[level] = level_states
+        return out
+
+    def _dispatch_level(self, level, joined, t0_vec, tail_cap,
+                        old_counts_dev):
+        """Split one level's rows into the warm and cold families and
+        dispatch both; returns each session's row keys, the dispatched
+        parts (per family, ``(rows by slot, device outputs)`` per chunk)
+        and the ``pool.count`` span's stats."""
         cfg = self.cfg
         keys_of: Dict[int, list] = {}
         warm_rows: Dict[int, np.ndarray] = {}
@@ -400,6 +463,7 @@ class StreamingCorpusMiner:
         chunk = max(cfg.max_candidates, 1)
         cold_tail = plan_mod.capacity_class(cold_need, floor=16)
         dispatched = []   # (rows_of, chunk parts)
+        widths = []       # the batch width of every dispatched chunk
 
         def family(rows_of, tail, t0, oc):
             """Dispatch one row family, chunked along the batch axis; all
@@ -414,6 +478,7 @@ class StreamingCorpusMiner:
                 # discipline) so ragged last chunks reuse warmed buckets
                 bc = plan_mod.capacity_class(
                     min(chunk, b_max - start), floor=16)
+                widths.append(bc)
                 sym = np.zeros((self.n_slots, bc, level), np.int32)
                 pe = np.full((self.n_slots, bc), -np.inf, np.float32)
                 pc = np.zeros((self.n_slots, bc), np.int32)
@@ -444,28 +509,10 @@ class StreamingCorpusMiner:
         family(cold_rows, cold_tail,
                np.full((self.n_slots,), -np.inf, np.float32),
                np.zeros((self.n_slots, self.n_types), np.int32))
-
-        fetched = jax.device_get(
-            [[p[1] for p in parts] for parts in dispatched])      # ONE sync
-        out: Dict[int, np.ndarray] = {
-            i: np.zeros((len(keys_of[i]),), np.int64) for i in joined}
-        for parts, vals in zip(dispatched, fetched):
-            for (sel, _), (cnt, end, _, ovf, short) in zip(parts, vals):
-                for i, rows in sel.items():
-                    m = rows.size
-                    if short[i, :m].any():
-                        raise RuntimeError(_TAIL_SHORT_MSG)
-                    if ovf[i, :m].any():
-                        raise RuntimeError(
-                            f"session slot {i}: {_OVERFLOW_MSG}")
-                    st = self._slots[i]
-                    cache = st.cache[level]
-                    out[i][rows] = cnt[i, :m]
-                    for j, r in enumerate(rows):
-                        cache[keys_of[i][r]] = _ChainState(
-                            prev_end=float(end[i, j]),
-                            count=int(cnt[i, j]), seq=st.seq)
-        return out
+        stats = dict(warm=sum(int(r.size) for r in warm_rows.values()),
+                     cold=sum(int(r.size) for r in cold_rows.values()),
+                     padded=self.n_slots * sum(widths))
+        return keys_of, dispatched, stats
 
     # -- results / warm protocol -------------------------------------------
 
